@@ -88,6 +88,7 @@ def population_worker(n_devices: int, smoke: bool) -> dict:
             "max_drawn_lag": tel.get("max_drawn_lag"),
         }
     return {
+        "backend": jax.default_backend(),
         "devices": n_devices,
         "jax_device_count": jax.device_count(),
         "n_clients": N_CLIENTS,
@@ -145,18 +146,24 @@ def main() -> dict:
     ap.add_argument("--population-worker", type=int, default=None,
                     help=argparse.SUPPRESS)     # internal: one sweep point
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.population_worker is not None:
         print(json.dumps(population_worker(args.population_worker,
                                            args.smoke)))
         return {}
+    # the parent never initializes JAX (it would hold the accelerator
+    # the children need); the backend comes from the first child's record
+    sweep = device_sweep([int(x) for x in args.devices.split(",")],
+                         args.smoke)
     result = {
-        "backend": jax.default_backend(),
+        "backend": next((r["backend"] for r in sweep.values()
+                         if "backend" in r), None),
         "mode": "smoke" if args.smoke else "full",
         "n_clients": N_CLIENTS,
         "cohort": COHORT,
         "batch": BATCH,
-        "device_sweep": device_sweep(
-            [int(x) for x in args.devices.split(",")], args.smoke),
+        "device_sweep": sweep,
     }
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)

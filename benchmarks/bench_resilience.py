@@ -109,6 +109,7 @@ def resilience_worker(n_devices: int, smoke: bool) -> dict:
         }
     off, on = base["guard_off"], base["guard_on"]
     return {
+        "backend": jax.default_backend(),
         "devices": n_devices,
         "jax_device_count": jax.device_count(),
         "rounds": rounds,
@@ -166,19 +167,24 @@ def main() -> dict:
     ap.add_argument("--resilience-worker", type=int, default=None,
                     help=argparse.SUPPRESS)     # internal: one sweep point
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.resilience_worker is not None:
         print(json.dumps(resilience_worker(args.resilience_worker,
                                            args.smoke)))
         return {}
-    import jax
+    # the parent never initializes JAX (it would hold the accelerator
+    # the children need); the backend comes from the first child's record
+    sweep = device_sweep([int(x) for x in args.devices.split(",")],
+                         args.smoke)
     result = {
-        "backend": jax.default_backend(),
+        "backend": next((r["backend"] for r in sweep.values()
+                         if "backend" in r), None),
         "mode": "smoke" if args.smoke else "full",
         "n_clients": N_CLIENTS,
         "attendance": ATTENDANCE,
         "batch": BATCH,
-        "device_sweep": device_sweep(
-            [int(x) for x in args.devices.split(",")], args.smoke),
+        "device_sweep": sweep,
     }
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)

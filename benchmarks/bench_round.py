@@ -339,6 +339,7 @@ def shard_local_worker(n_devices: int, smoke: bool) -> dict:
     loss_off = float(_round_call(eng_off)())
     loss_on = float(_round_call(eng_on)())
     return {
+        "backend": jax.default_backend(),
         "devices": n_devices,
         "jax_device_count": jax.device_count(),
         "off_steady_ms": round(off_ms * 1e3, 3),
@@ -376,6 +377,16 @@ def _forced_device_sweep(worker_flag: str, devices: list[int], smoke: bool,
         out[str(n)] = rec
         print(report(rec))
     return out
+
+
+def child_backend(*sweeps: dict):
+    """The backend the first successful child reported (None if none
+    did) — read from the records, so the parent never initializes JAX."""
+    for sweep in sweeps:
+        for rec in sweep.values():
+            if isinstance(rec, dict) and "backend" in rec:
+                return rec["backend"]
+    return None
 
 
 def shard_local_sweep(devices: list[int], smoke: bool) -> dict:
@@ -451,6 +462,7 @@ def sweep_worker(n_devices: int, smoke: bool) -> dict:
     cfg = _ws_config(n_devices, rounds)
     eng, rec = _ws_run(cfg, n_devices)
     rec = {
+        "backend": jax.default_backend(),
         "devices": n_devices,
         "jax_device_count": jax.device_count(),
         "cohort_capacity": eng.cohort_capacity,
@@ -562,6 +574,8 @@ def main() -> dict:
     ap.add_argument("--shard-local-worker", type=int, default=None,
                     help=argparse.SUPPRESS)     # internal: one sweep point
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.sweep_worker is not None:
         print(json.dumps(sweep_worker(args.sweep_worker, args.smoke)))
         return {}
@@ -569,19 +583,25 @@ def main() -> dict:
         print(json.dumps(shard_local_worker(args.shard_local_worker,
                                             args.smoke)))
         return {}
-    result = ({"backend": jax.default_backend(),
-               "mode": "smoke" if args.smoke else "full"}
-              if args.sweep_only else run(smoke=args.smoke))
+    # the per-device-count children run first: a parent that has touched
+    # JAX holds the accelerator, and a child could then not open it
+    sweeps = {}
+    if args.devices:
+        sweeps["device_sweep"] = device_sweep(
+            [int(x) for x in args.devices.split(",")], args.smoke)
+    if args.shard_local:
+        sweeps["shard_local"] = shard_local_sweep(
+            [int(x) for x in args.shard_local.split(",")], args.smoke)
+    if args.sweep_only:
+        result = {"backend": child_backend(*sweeps.values()),
+                  "mode": "smoke" if args.smoke else "full"}
+    else:
+        result = run(smoke=args.smoke)
     if args.pipeline:
         result["pipeline_comparison"] = pipeline_sweep(
             args.smoke,
             tuple(int(x) for x in args.pipeline_depths.split(",")))
-    if args.devices:
-        result["device_sweep"] = device_sweep(
-            [int(x) for x in args.devices.split(",")], args.smoke)
-    if args.shard_local:
-        result["shard_local"] = shard_local_sweep(
-            [int(x) for x in args.shard_local.split(",")], args.smoke)
+    result.update(sweeps)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     print(f"wrote {args.out}")

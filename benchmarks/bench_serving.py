@@ -37,6 +37,8 @@ def main() -> dict:
                     help="comma-separated concurrent-client counts")
     ap.add_argument("--out", default="BENCH_serving.json")
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
 

@@ -146,6 +146,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma list: kernels,round,table3..table8,roofline")
     args, _ = ap.parse_known_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     rows = []

@@ -39,8 +39,10 @@ from repro.core.protocol import (EntityState, broadcast_entity, entity_mean,
 from repro.core.split import SplitTask
 from repro.optim import Optimizer
 from repro.resilience.guards import health_vector
-from repro.sharding.specs import (constrain_cohort, constrain_cohort_tree,
-                                  constrain_entity_params, slot_shard_map)
+from repro.sharding.specs import (cohort_entity_step, constrain_cohort,
+                                  constrain_cohort_tree,
+                                  constrain_entity_params,
+                                  sharded_entity_step, slot_shard_map)
 
 
 class TrainState(NamedTuple):
@@ -260,7 +262,8 @@ class ServerUpdate(Phase):
                     lambda g: masked_axis0_mean(g, v.mask), gs)
             if v.stale_w is not None:
                 gmean = jax.tree.map(lambda g: g * v.stale_w, gmean)
-            server = entity_step(v.state.server, gmean, ctx.opt_server)
+            server = sharded_entity_step(v.state.server, gmean,
+                                         ctx.opt_server, ctx.mesh)
             v.metrics["server_loss"] = masked_mean(losses, v.mask)
         else:
             raise ValueError(f"unknown ServerUpdate mode {self.mode!r}")
@@ -313,7 +316,7 @@ class ClientUpdate(Phase):
                 def body(entity, inp):
                     x, g = inp
                     return client_update_one(ctx.task, entity, x, g,
-                                             ctx.opt_client, clip)
+                                             ctx.opt_client, clip, ctx.mesh)
                 v.cohort_clients, gnorms = jax.lax.scan(
                     body, v.state.client_global, (v.xs, v.fgrads))
             else:
@@ -321,7 +324,8 @@ class ClientUpdate(Phase):
                 def body(entity, inp):
                     x, g, m = inp
                     new, gn = client_update_one(ctx.task, entity, x, g,
-                                                ctx.opt_client, clip)
+                                                ctx.opt_client, clip,
+                                                ctx.mesh)
                     return (select_entities(m, new, entity),
                             jnp.where(m > 0, gn, 0.0))
                 v.cohort_clients, gnorms = jax.lax.scan(
@@ -416,8 +420,8 @@ class SequentialChainRound(Phase):
             f = task.client_forward(client.params, x)
             fg = jax.grad(lambda ff: task.server_loss(
                 jax.lax.stop_gradient(server.params), ff, y))(f)
-            new_s = entity_step(server, gs, opt_s)
-            new_c = entity_step(client, gc, opt_c)
+            new_s = sharded_entity_step(server, gs, opt_s, ctx.mesh)
+            new_c = sharded_entity_step(client, gc, opt_c, ctx.mesh, "full")
             if masked:
                 m = inp[2]
                 new_s = select_entities(m, new_s, server)
@@ -454,7 +458,7 @@ class ServerSequentialRound(Phase):
             f = task.client_forward(cp, x)
             fg = jax.grad(lambda ff: task.server_loss(
                 jax.lax.stop_gradient(server.params), ff, y))(f)
-            new_s = entity_step(server, gs, opt_s)
+            new_s = sharded_entity_step(server, gs, opt_s, ctx.mesh)
             if masked:
                 m = inp[3]
                 new_s = select_entities(m, new_s, server)
@@ -465,8 +469,8 @@ class ServerSequentialRound(Phase):
                   else (cohort_clients.params, v.xs, v.ys))
         server, (losses, gc, fg) = jax.lax.scan(
             body, v.state.server, inputs)
-        stepped = jax.vmap(
-            lambda e, g: entity_step(e, g, ctx.opt_client))(cohort_clients, gc)
+        stepped = cohort_entity_step(cohort_clients, gc, ctx.opt_client,
+                                     ctx.mesh)
         client_global = (entity_mean(stepped) if not masked
                          else masked_entity_mean(stepped, v.mask))
         v.metrics.update(server_loss=masked_mean(losses, v.mask),

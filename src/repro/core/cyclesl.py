@@ -29,10 +29,11 @@ from repro.core.feature_store import (FeatureStore, gather_batch,
                                       masked_resample_plan, pool_store,
                                       resample_plan, shard_local_fused_loss,
                                       shard_local_gather)
-from repro.core.protocol import (EntityState, entity_step, masked_axis0_mean,
+from repro.core.protocol import (EntityState, masked_axis0_mean,
                                  select_entities)
 from repro.core.split import SplitTask
 from repro.optim import Optimizer, clip_by_global_norm
+from repro.sharding.specs import sharded_entity_step
 
 
 def _maybe_clip(grads, max_norm: Optional[float]):
@@ -102,7 +103,9 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
     (see :func:`gather_batch`).  ``ccfg.shard_local_resample`` + mesh
     routes the gather through :func:`shard_local_gather` instead — the
     shard_map wrapper whose per-shard index translation keeps the
-    resample shard-LOCAL (bit-for-bit the GSPMD path).
+    resample shard-LOCAL (bit-for-bit the GSPMD path).  On a TPU mesh of
+    several devices the kernel paths always take that route: XLA cannot
+    partition a compiled Pallas call.
     ``ccfg.fused_gather_loss`` additionally fuses gather and head loss
     through ``kernels.ops.fused_gather_loss_mean`` when the task
     exposes a linear server head.  ``mesh=None`` leaves placement to
@@ -111,7 +114,21 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
     step — the staleness-weighting hook; 1.0 is an exact no-op.
     """
     sb = min(ccfg.server_batch or batch, store.size)
-    shard_local = ccfg.shard_local_resample and mesh is not None
+    # fused path: linear head + single integer label leaf (see below)
+    fused = (ccfg.fused_gather_loss
+             and getattr(task, "server_head", None) is not None
+             and isinstance(store.labels, jax.Array)
+             and jnp.issubdtype(store.labels.dtype, jnp.integer))
+    # a compiled (Mosaic) kernel cannot be partitioned by XLA: on a
+    # multi-device TPU mesh the kernel paths run inside the shard_map
+    # wrappers, which are value-exact for the gather
+    from repro.kernels.ops import default_interpret
+    mosaic = not default_interpret()
+    use_kernel = (mosaic if ccfg.resample_use_kernel is None
+                  else ccfg.resample_use_kernel)
+    shard_local = mesh is not None and (
+        ccfg.shard_local_resample
+        or (mosaic and mesh.size > 1 and (use_kernel or fused)))
     # minibatch layout: tensor-parallel (replicated rows) when the
     # server params are FSDP/TP-sharded on this mesh — row-sharding the
     # batch on the same axis as the weights forces a full weight
@@ -122,16 +139,12 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
         tp_layout = params_are_sharded(server.params, mesh, "server")
     else:
         tp_layout = False
-    # fused path: linear head + single integer label leaf.  On a sharded
-    # mesh this composes with the shard-local resample through
-    # shard_local_fused_loss — the per-row loss runs INSIDE the
-    # shard_map body over each shard's pool slice and only a scalar
-    # psum crosses devices, so the fused kernel no longer reintroduces
-    # the feature-pool all-gather the shard-local route exists to avoid.
-    fused = (ccfg.fused_gather_loss
-             and getattr(task, "server_head", None) is not None
-             and isinstance(store.labels, jax.Array)
-             and jnp.issubdtype(store.labels.dtype, jnp.integer))
+    # fused path on a sharded mesh: composes with the shard-local
+    # resample through shard_local_fused_loss — the per-row loss runs
+    # INSIDE the shard_map body over each shard's pool slice and only a
+    # scalar psum crosses devices, so the fused kernel no longer
+    # reintroduces the feature-pool all-gather the shard-local route
+    # exists to avoid.
     if store.valid is None:
         plan = resample_plan(key, store.size, ccfg.server_epochs, sb)
         step_ok = None
@@ -148,7 +161,7 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
         w = task.server_head(params)
         if shard_local:
             return shard_local_fused_loss(store, idx, w, mesh,
-                                          use_kernel=ccfg.resample_use_kernel)
+                                          use_kernel=use_kernel)
         from repro.kernels import ops
         return ops.fused_gather_loss_mean(
             store.features.reshape((store.size, -1)), store.labels, idx, w)
@@ -160,11 +173,10 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
         else:
             if shard_local:
                 f, y = shard_local_gather(store, idx, mesh,
-                                          use_kernel=ccfg.resample_use_kernel,
+                                          use_kernel=use_kernel,
                                           replicate_out=tp_layout)
             else:
-                f, y = gather_batch(store, idx,
-                                    use_kernel=ccfg.resample_use_kernel)
+                f, y = gather_batch(store, idx, use_kernel=use_kernel)
             if mesh is not None:
                 from repro.sharding.specs import constrain_server_batch
                 f, y = constrain_server_batch(f, y, mesh,
@@ -176,7 +188,7 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
             # staleness weighting: a traced scalar so one trace serves
             # every realized lag; scale == 1.0 is an exact no-op
             grads = jax.tree.map(lambda g: g * grad_scale, grads)
-        return entity_step(entity, grads, opt_s), loss
+        return sharded_entity_step(entity, grads, opt_s, mesh), loss
 
     if step_ok is None:
         server, losses = jax.lax.scan(apply_step, server, plan2)
@@ -227,13 +239,15 @@ def feature_gradients(task: SplitTask, server_params, feats, ys,
 
 def client_update_one(task: SplitTask, entity: EntityState, x, g,
                       opt_c: Optimizer,
-                      grad_clip: Optional[float] = None
-                      ) -> tuple[EntityState, jnp.ndarray]:
+                      grad_clip: Optional[float] = None,
+                      mesh=None) -> tuple[EntityState, jnp.ndarray]:
     """One client's phase-5 step: pull its feature gradient ``g`` through
     the local VJP, optionally clip, and take one optimizer step.
 
     The single source of truth for the client update — the cohort-vmapped
     :func:`client_updates` and the sequential (cyclessl) chain both call it.
+    ``mesh`` is for a shared client entity stepped outside any manual
+    region (see :func:`repro.sharding.specs.sharded_entity_step`).
     Returns the stepped entity and the global norm of the applied grads.
     """
     def fwd(p):
@@ -243,7 +257,7 @@ def client_update_one(task: SplitTask, entity: EntityState, x, g,
     grads = _maybe_clip(grads, grad_clip)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                          for l in jax.tree.leaves(grads)))
-    return entity_step(entity, grads, opt_c), gnorm
+    return sharded_entity_step(entity, grads, opt_c, mesh, "full"), gnorm
 
 
 def client_updates(task: SplitTask, clients: EntityState, opt_c: Optimizer,

@@ -117,19 +117,17 @@ def gather_batch(store: FeatureStore, idx,
 
     Backend-gated like ``fused_adam``: on TPU the row gather dispatches
     to the ``kernels.ops.feature_resample`` scalar-prefetch Pallas
-    kernel (indices in SMEM, one source row-block streamed per output
-    row-block — a pure HBM-bandwidth copy); elsewhere the XLA
-    ``jnp.take`` lowering is kept (``use_kernel=True`` forces the kernel
-    in interpret mode, which is what the CPU equivalence test
+    kernel (indices in SMEM, 8 rows gathered per grid step); elsewhere
+    the XLA ``jnp.take`` lowering is kept (``use_kernel=True`` forces
+    the kernel in interpret mode, which is what the CPU equivalence test
     exercises).  Both paths compute the identical gather.
 
-    Caveat: GSPMD has no partitioning rule for a bare ``pallas_call``,
-    so on a mesh with the pool sharded over 'data' XLA gathers the
-    operand around the kernel — correct, but the gather is not
-    shard-LOCAL.  :func:`shard_local_gather` is the ``shard_map`` wrapper
-    with per-shard index translation that keeps it local (CycleConfig.
-    shard_local_resample routes the server inner loop there); the jnp
-    path partitions natively.
+    Caveat: XLA cannot partition a compiled Pallas call, so in a jit
+    over several TPU devices the kernel must run inside a ``shard_map``:
+    :func:`shard_local_gather` is that wrapper, with per-shard index
+    translation that keeps the gather local (the server inner loop
+    routes there on a multi-device TPU mesh).  The jnp path, and the
+    interpreted kernel, partition natively.
     """
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
@@ -166,9 +164,9 @@ def shard_local_gather(store: FeatureStore, idx, mesh,
     """Shard-LOCAL resample: ``out[i] = store[idx[i]]`` without gathering
     the pooled operand around the kernel.
 
-    GSPMD has no partitioning rule for a bare ``pallas_call``, so the
-    kernel path of :func:`gather_batch` all-gathers D_S^f per minibatch
-    on a sharded mesh.  This wrapper keeps the gather local: a
+    XLA cannot partition a compiled ``pallas_call``, so on a sharded
+    TPU mesh the kernel path of :func:`gather_batch` needs a
+    ``shard_map`` around it.  This wrapper keeps the gather local: a
     ``shard_map`` over the pool's batch axes gives each shard only its
     contiguous row slice, per-shard index translation
     (:func:`shard_slice_indices`) selects the plan rows that land in the
@@ -199,7 +197,6 @@ def shard_local_gather(store: FeatureStore, idx, mesh,
     axes, n_shards, rows_per_shard = info
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lead = axes if len(axes) > 1 else axes[0]
@@ -236,14 +233,14 @@ def shard_local_gather(store: FeatureStore, idx, mesh,
 
         return take(feats), jax.tree.map(take, labels)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(row_spec(store.features),
                   jax.tree.map(row_spec, store.labels),
                   P(None)),
         out_specs=(out_spec(store.features),
                    jax.tree.map(out_spec, store.labels)),
-        check_rep=False)
+        check_vma=False)
     return fn(store.features, store.labels, idx.astype(jnp.int32))
 
 
@@ -283,7 +280,6 @@ def shard_local_fused_loss(store: FeatureStore, idx, w, mesh,
     axes, n_shards, rows_per_shard = info
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lead = axes if len(axes) > 1 else axes[0]
@@ -320,13 +316,14 @@ def shard_local_fused_loss(store: FeatureStore, idx, w, mesh,
         return jax.lax.psum(f.T @ dlog, lead).astype(w.dtype)
 
     row = lambda a: P(lead, *([None] * (a.ndim - 1)))
-    fwd_sm = shard_map(fwd_body, mesh=mesh,
-                       in_specs=(row(feats2), P(lead), P(None), P(None, None)),
-                       out_specs=P(), check_rep=False)
-    bwd_sm = shard_map(bwd_body, mesh=mesh,
-                       in_specs=(row(feats2), P(lead), P(None), P(None, None),
-                                 P()),
-                       out_specs=P(None, None), check_rep=False)
+    fwd_sm = jax.shard_map(fwd_body, mesh=mesh,
+                           in_specs=(row(feats2), P(lead), P(None),
+                                     P(None, None)),
+                           out_specs=P(), check_vma=False)
+    bwd_sm = jax.shard_map(bwd_body, mesh=mesh,
+                           in_specs=(row(feats2), P(lead), P(None),
+                                     P(None, None), P()),
+                           out_specs=P(None, None), check_vma=False)
 
     @jax.custom_vjp
     def fused(feats2, labels, idx, w):

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels are TPU-targeted and validated against ``ref.py`` in interpret
-mode, per the repo's hardware-adaptation contract).
+On a TPU backend the kernels run compiled (Mosaic).  On any other
+backend they run in the Pallas interpreter, which is how the CPU test
+suite checks them against ``ref.py``; ``tests/test_tpu_compile.py``
+compiles the main-path kernels for a described TPU v5e without one.
 """
 from __future__ import annotations
 
@@ -20,12 +21,8 @@ from repro.kernels import topk_gating as _tk
 
 def default_interpret() -> bool:
     """One backend gate for every kernel: compiled on TPU, Pallas
-    interpreter everywhere else (the kernels are TPU-targeted and the
-    interpreter is the validated CPU fallback)."""
+    interpreter everywhere else (the interpreter is for CPU tests)."""
     return jax.default_backend() != "tpu"
-
-
-_default_interpret = default_interpret            # backwards-compat alias
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap",

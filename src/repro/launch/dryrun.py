@@ -37,6 +37,11 @@ from repro.utils import hlo, hlo_cost
 # variant (long_context=True), SSM/hybrid run natively.
 LONG_SKIP = {"whisper-base": "enc-dec, 448-pos decoder horizon; full attn"}
 
+# the chip the production meshes stand for (launch/mesh.py); the forced
+# host devices only stand in for it, so records name it explicitly and
+# launch/roofline.py reads its peaks from the record
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _mem_stats(compiled) -> dict:
     out = {}
@@ -71,7 +76,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     shape = INPUT_SHAPES[shape_name]
     rec = {"arch": arch, "shape": shape_name,
            "mesh": "2x16x16" if multi_pod else "16x16",
-           "status": "ok"}
+           "device_kind": TARGET_DEVICE_KIND, "status": "ok"}
     if server_batch:
         rec["server_batch"] = server_batch
     if shape_name == "long_500k" and arch in LONG_SKIP:

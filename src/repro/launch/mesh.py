@@ -6,12 +6,24 @@ Multi-pod  : (pod=2, data=16, model=16)     = 512 chips
 Functions, not module constants, so importing this module never touches
 jax device state (the dry-run forces 512 host devices *before* any jax
 initialization — see dryrun.py).
+
+Every mesh is built by :func:`auto_mesh`, with ``Auto`` axis types:
+``jax.make_mesh`` defaults to ``Explicit`` axes, which reject the
+``with_sharding_constraint`` placement pins the round programs thread
+(:mod:`repro.sharding.specs`).
 """
 from __future__ import annotations
 
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` over ``devices`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,12 +35,12 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, found {len(devices)} — run via "
             "launch/dryrun.py which forces XLA_FLAGS host device count")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return auto_mesh(shape, axes, devices[:n])
 
 
 def make_local_mesh():
     """Degenerate 1x1 mesh for CPU tests/benchmarks."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    return auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def make_engine_mesh(shape, axes):
@@ -51,7 +63,7 @@ def make_engine_mesh(shape, axes):
             f"mesh {shape} needs {n} devices, found {len(devices)} — set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
             "jax initializes (see benchmarks/bench_round.py --devices)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return auto_mesh(shape, axes, devices[:n])
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

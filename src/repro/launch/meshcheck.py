@@ -52,6 +52,7 @@ from repro.api import PROGRAMS, build_algorithm, get_program
 from repro.api.phases import build_pipelined_algorithm
 from repro.core.cyclesl import CycleConfig
 from repro.core.split import make_stage_task
+from repro.launch.mesh import auto_mesh
 from repro.models.cnn import mlp
 from repro.optim import adam
 from repro.sharding.specs import batch_spec, train_state_shardings
@@ -144,8 +145,7 @@ def _max_diff(a_state, a_rows, b_state, b_rows) -> float:
 
 def check_algorithm(name, task, xs, ys, meshN, tol: float) -> dict:
     base_state, base_rows, _ = _drive(name, task, xs, ys)
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
+    mesh1 = auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
     s1, r1, _ = _drive(name, task, xs, ys, mesh1)
     sN, rN, traces = _drive(name, task, xs, ys, meshN)
     d1 = _max_diff(base_state, base_rows, s1, r1)
@@ -200,14 +200,12 @@ def main() -> int:
                           f"{jax.device_count()} (run via python -m, the "
                           "__main__ guard forces the host device count)"}))
         return 2
-    meshN = jax.make_mesh((n, 1), ("data", "model"),
-                          devices=jax.devices()[:n])
+    meshN = auto_mesh((n, 1), ("data", "model"), jax.devices()[:n])
     task, xs, ys = _task_and_data()
     algos = (args.algos.split(",") if args.algos else sorted(PROGRAMS))
     report = {"devices": n, "capacity": C, "rounds": ROUNDS, "algos": {}}
     if args.shard_local:
-        mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                              devices=jax.devices()[:1])
+        mesh1 = auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
         meshes = [("1dev", mesh1), (f"{n}dev", meshN)]
         report["mode"] = "shard_local"
         for name in algos:

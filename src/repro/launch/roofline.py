@@ -3,9 +3,12 @@
 Derives the three roofline terms per (arch × shape × mesh) from
 ``benchmarks/results/dryrun.json``:
 
-    compute    = HLO_FLOPs_global    / (chips × 197e12 FLOP/s bf16)
-    memory     = HLO_bytes_global    / (chips × 819e9  B/s HBM)
-    collective = collective_bytes    / (chips × 50e9   B/s ICI per link)
+    compute    = HLO_FLOPs_global    / (chips × peak FLOP/s bf16)
+    memory     = HLO_bytes_global    / (chips × peak HBM B/s)
+    collective = collective_bytes    / (chips × ICI B/s per link)
+
+with the peaks of the record's ``device_kind`` looked up in
+:data:`PEAKS`; a device that is not in the table raises.
 
 Calibration notes (verified empirically in tests/test_roofline.py):
   * ``compiled.cost_analysis()`` on an SPMD-partitioned module reports
@@ -32,9 +35,23 @@ import json
 from repro.configs import INPUT_SHAPES
 from repro.configs.registry import get_config
 
-PEAK_FLOPS = 197e12          # per chip, bf16
-HBM_BW = 819e9               # per chip, bytes/s
-ICI_BW = 50e9                # per link, bytes/s
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of interconnect (4 links x 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s_per_link": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The :data:`PEAKS` row of ``device_kind``; an unknown device is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -67,11 +84,12 @@ def analyze_record(rec: dict) -> dict | None:
     # collective bytes: loop-aware number is per-device operand bytes
     coll_dev = (la.get("collective_bytes")
                 or rec.get("collectives", {}).get("total_bytes", 0))
+    peak = peaks(rec["device_kind"])
     flops_glob = flops_dev * chips
     bytes_glob = bytes_dev * chips
-    t_compute = flops_glob / (chips * PEAK_FLOPS)
-    t_memory = bytes_glob / (chips * HBM_BW)
-    t_coll = coll_dev / ICI_BW
+    t_compute = flops_glob / (chips * peak["flops"])
+    t_memory = bytes_glob / (chips * peak["hbm_bytes_per_s"])
+    t_coll = coll_dev / peak["ici_bytes_per_s_per_link"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["shape"])

@@ -133,6 +133,8 @@ def main():
     from repro.serve import ServeConfig
     ServeConfig.add_arguments(ap)
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else smoke_config(args.arch)
     if args.continuous:
         if cfg.family == "audio":

@@ -1,4 +1,4 @@
-"""Federated split-learning training CLI (runs for real on CPU).
+"""Federated split-learning training CLI.
 
 Thin flag-parsing front-end over the one driver loop,
 ``repro.api.Engine``: build an :class:`~repro.api.ExperimentConfig`
@@ -17,11 +17,8 @@ import json
 import os
 
 from repro.api import Engine, ExperimentConfig
-# re-exported for backwards compatibility (tests and notebooks import
-# these from here; they now live in repro.api)
-from repro.api.engine import evaluate            # noqa: F401
-from repro.api.tasks import build_task           # noqa: F401
 from repro.core.cyclesl import CycleConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(algo_name: str, task_name: str = "image", rounds: int = 100,
@@ -45,6 +42,7 @@ def main():
     ExperimentConfig.add_arguments(ap)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = ExperimentConfig.from_flags(args)
     res = Engine(cfg).run()
     if args.out:

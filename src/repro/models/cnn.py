@@ -6,7 +6,7 @@ cut index selects how many stages stay on the client — exactly the
 paper's block-wise cut ablation (Table 4).
 
 Conv layers use NHWC and ``lax.conv_general_dilated``; everything is
-float32 and CPU-friendly (the paper-claims benchmarks run for real).
+float32.  The same code runs on CPU (tests) and TPU (``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn_lib
@@ -32,8 +33,10 @@ from repro.models.module import stacked_init
 from repro.sharding.specs import constrain_batch
 from repro.utils.tree import tree_slice
 
-ZERO_METRICS = {"aux_loss": jnp.zeros((), jnp.float32),
-                "z_loss": jnp.zeros((), jnp.float32)}
+# numpy, not jnp: a jnp array made at import would initialize the JAX
+# backend, and a process that has done that holds the accelerator
+ZERO_METRICS = {"aux_loss": np.zeros((), np.float32),
+                "z_loss": np.zeros((), np.float32)}
 
 
 # ---------------------------------------------------------------- helpers

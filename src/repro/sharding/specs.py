@@ -240,13 +240,7 @@ def batch_spec(mesh: Mesh, batch: int, extra_dims: int = 1) -> P:
 # neutral (with_sharding_constraint only pins layout), which is what
 # makes the 1-device-mesh path bit-for-bit equal to the unsharded one.
 def _wsc(x, mesh: Mesh, spec: P):
-    from jax.lax import with_sharding_constraint
-    try:
-        return with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except Exception:
-        if isinstance(x, jax.core.Tracer):
-            raise               # inside a trace a bad spec is a real bug
-        return x                # eager/abstract use: layout hint only
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def constrain_cohort(x, mesh: Optional[Mesh]):
@@ -415,7 +409,6 @@ def slot_shard_map(fn, mesh: Optional[Mesh], slot_args: tuple,
     axes = cohort_shard_axes(mesh, C)
     if axes is None:
         return fn(*slot_args, *rep_args)
-    from jax.experimental.shard_map import shard_map
     lead = axes if len(axes) > 1 else axes[0]
 
     def sspec(l):
@@ -425,13 +418,50 @@ def slot_shard_map(fn, mesh: Optional[Mesh], slot_args: tuple,
         return P(*([None] * getattr(l, "ndim", 0)))
 
     out_shape = jax.eval_shape(lambda s, r: fn(*s, *r), slot_args, rep_args)
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         lambda s, r: fn(*s, *r), mesh=mesh,
         in_specs=(jax.tree.map(sspec, slot_args),
                   jax.tree.map(rspec, rep_args)),
         out_specs=jax.tree.map(sspec, out_shape),
-        check_rep=False)
+        check_vma=False)
     return wrapped(slot_args, rep_args)
+
+
+def sharded_entity_step(entity, grads, opt, mesh: Optional[Mesh],
+                        role: str = "server"):
+    """:func:`repro.core.protocol.entity_step` that compiles on a
+    multi-device TPU mesh.
+
+    A fused optimizer (``opt.apply``: the Pallas fused-Adam kernel) is a
+    Mosaic custom call, and XLA cannot partition one — inside a jit over
+    several devices it must sit in a ``shard_map``.  So on such a mesh
+    the step runs inside a ``shard_map`` over the entity's path-rule
+    placement for ``role`` ('server', or 'full' for a single shared
+    client entity): each device updates its own block of every leaf.
+    The update is elementwise, so the values are the unsharded step's.
+    Off-mesh, on one device, or with an unfused optimizer this is the
+    plain ``entity_step``.  Call it only outside a manual region.
+    """
+    from repro.core.protocol import entity_step
+    if (mesh is None or mesh.size == 1
+            or getattr(opt, "apply", None) is None):
+        return entity_step(entity, grads, opt)
+    especs = param_specs(entity, mesh, role)
+    return jax.shard_map(
+        lambda e, g: entity_step(e, g, opt), mesh=mesh,
+        in_specs=(especs, param_specs(grads, mesh, role)),
+        out_specs=especs, check_vma=False)(entity, grads)
+
+
+def cohort_entity_step(entities, grads, opt, mesh: Optional[Mesh]):
+    """Vmapped :func:`entity_step` over a [C, ...] cohort stack; with a
+    fused optimizer it runs in :func:`slot_shard_map`, for the reason
+    :func:`sharded_entity_step` gives."""
+    from repro.core.protocol import entity_step
+    step = jax.vmap(lambda e, g: entity_step(e, g, opt))
+    if getattr(opt, "apply", None) is None:
+        return step(entities, grads)
+    return slot_shard_map(step, mesh, (entities, grads))
 
 
 def train_state_shardings(state, mesh: Mesh, moe_shard_mode: str = "expert",

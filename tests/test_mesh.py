@@ -23,6 +23,7 @@ import pytest
 
 from repro.api import Engine, ExperimentConfig, build_algorithm, get_program
 from repro.core.feature_store import FeatureStore, gather_batch
+from repro.launch.mesh import auto_mesh
 from repro.launch.meshcheck import C, _drive, _task_and_data
 from repro.optim import adam
 from repro.sharding.specs import train_state_shardings
@@ -36,8 +37,7 @@ def setup():
 
 
 def _mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    return auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def _assert_equal(a_state, a_rows, b_state, b_rows, msg):
@@ -190,8 +190,7 @@ def _n_mesh():
     devices8/kernels legs, 1 on the default single-CPU-device run (where
     the 8-device case is covered by the subprocess golden below)."""
     n = 8 if jax.device_count() >= 8 else 1
-    return jax.make_mesh((n, 1), ("data", "model"),
-                         devices=jax.devices()[:n])
+    return auto_mesh((n, 1), ("data", "model"), jax.devices()[:n])
 
 
 @pytest.mark.kernels

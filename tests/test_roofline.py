@@ -86,7 +86,7 @@ def test_model_flops_moe_uses_active_params():
 def test_analyze_record_terms():
     rec = {
         "status": "ok", "arch": "phi3-mini-3.8b", "shape": "train_4k",
-        "n_devices": 256,
+        "n_devices": 256, "device_kind": "TPU v5 lite",
         "loop_aware": {"flops": 1e14, "traffic_bytes": 1e12,
                        "collective_bytes": 5e10},
         "cost": {}, "collectives": {},
@@ -96,3 +96,14 @@ def test_analyze_record_terms():
     assert a["t_memory_s"] == pytest.approx(1e12 / 819e9)
     assert a["t_collective_s"] == pytest.approx(5e10 / 50e9)
     assert a["dominant"] == "t_memory_s".replace("t_", "").replace("_s", "")
+
+
+def test_analyze_record_unknown_device_raises():
+    """Peaks come from one table keyed by device_kind: a device without
+    published peaks is an error, never a default."""
+    rec = {"status": "ok", "arch": "phi3-mini-3.8b", "shape": "train_4k",
+           "n_devices": 4, "device_kind": "cpu",
+           "loop_aware": {"flops": 1.0, "traffic_bytes": 1.0,
+                          "collective_bytes": 1.0}}
+    with pytest.raises(KeyError, match="no published peaks"):
+        analyze_record(rec)

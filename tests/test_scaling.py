@@ -26,6 +26,7 @@ from repro.api import Engine, ExperimentConfig
 from repro.api.registry import PROGRAMS
 from repro.core.feature_store import FeatureStore, shard_local_fused_loss
 from repro.kernels import ops
+from repro.launch.mesh import auto_mesh
 from repro.utils.hlo_cost import assert_no_pool_allgather, collective_census
 from repro.utils.profiling import RoundProfiler, phase_costs, round_hlo
 
@@ -111,8 +112,7 @@ def test_padded_capacity_identity_off_mesh_and_at_one_device():
     align: no mesh, or a single batch shard."""
     from repro.sharding.specs import shard_aligned_capacity
     assert shard_aligned_capacity(None, 6) == 6
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
+    mesh1 = auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
     assert shard_aligned_capacity(mesh1, 6) == 6
     eng = Engine(ExperimentConfig(algo="cyclesfl", mesh_shape=(1, 1),
                                   **TINY), donate=False,
@@ -164,8 +164,7 @@ def test_shard_local_fused_loss_matches_unsharded_fused_kernel():
     Runs the widest mesh this process has; the forced 8-shard case is
     covered by the subprocess golden."""
     n = 8 if jax.device_count() >= 8 else 1
-    mesh = jax.make_mesh((n, 1), ("data", "model"),
-                         devices=jax.devices()[:n])
+    mesh = auto_mesh((n, 1), ("data", "model"), jax.devices()[:n])
     rng = np.random.default_rng(5)
     feats = jnp.asarray(rng.normal(size=(48, 24)), jnp.float32)
     labels = jnp.asarray(rng.integers(0, 10, size=(48,)), jnp.int32)
@@ -222,6 +221,7 @@ from repro.api.registry import PROGRAMS
 import jax.numpy as jnp
 from repro.core.feature_store import FeatureStore, shard_local_fused_loss
 from repro.kernels import ops
+from repro.launch.mesh import auto_mesh
 
 quiet = lambda *a, **k: None
 rep = {"devices": jax.device_count(), "algos": {}}
@@ -260,7 +260,7 @@ feats = jnp.asarray(rng.normal(size=(48, 24)), jnp.float32)
 labels = jnp.asarray(rng.integers(0, 10, size=(48,)), jnp.int32)
 idx = jnp.asarray(rng.integers(0, 48, size=(16,)), jnp.int32)
 w = jnp.asarray(rng.normal(size=(24, 10)) * 0.1, jnp.float32)
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = auto_mesh((8, 1), ("data", "model"), jax.devices()[:8])
 store = FeatureStore(feats, labels)
 ref_l, ref_dw = jax.value_and_grad(
     lambda w: ops.fused_gather_loss_mean(feats, labels, idx, w))(w)

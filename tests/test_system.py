@@ -11,7 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.train import build_task, evaluate, run
+from repro.api.engine import evaluate
+from repro.api.tasks import build_task
+from repro.launch.train import run
 from repro.core.algorithms import make_algorithm
 from repro.core.cyclesl import CycleConfig
 from repro.data.federated import sample_cohort
