@@ -34,10 +34,9 @@ algorithm:
                           ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
                           (jax locks the device count at first init).
                           Per point: steady latency, the fused
-                          gather+loss-inside-shard_map variant, a
-                          per-phase cost breakdown, and the collective
-                          census with the no-pool-allgather HLO
-                          assertion.  The sweep-level claim is
+                          gather+loss-inside-shard_map variant, and the
+                          collective census with the no-pool-allgather
+                          HLO assertion.  The sweep-level claim is
                           ``weak_scaling_efficiency`` = rps(max devices)
                           / rps(1 device) >= 1.0.
 * ``shard_local``       — (``--shard-local [1,8]``) the sharded Engine
@@ -454,10 +453,9 @@ def sweep_worker(n_devices: int, smoke: bool) -> dict:
     mesh (N, 1) over ('data', 'model'), shard-local resample, donated
     device-resident rounds, sync_every=4.  Records the plain shard-local
     round, the fused-in-shard_map variant (gather+head-loss computed
-    inside the shard_map body, scalar psum across shards), a per-phase
-    cost breakdown, and the collective census + no-pool-allgather
-    assertion for both compiled rounds."""
-    from repro.utils import profiling
+    inside the shard_map body, scalar psum across shards), and the
+    collective census + no-pool-allgather assertion for both compiled
+    rounds."""
     rounds = 6 if smoke else 10
     cfg = _ws_config(n_devices, rounds)
     eng, rec = _ws_run(cfg, n_devices)
@@ -469,8 +467,6 @@ def sweep_worker(n_devices: int, smoke: bool) -> dict:
         "padded_capacity": eng.padded_capacity,
         **rec,
     }
-    phases = profiling.phase_costs(eng, repeats=2 if smoke else 4)
-    rec["phase_ms"] = {k: v["delta_ms"] for k, v in phases.items()}
     _, frec = _ws_run(cfg.with_cycle(fused_gather_loss=True), n_devices)
     rec["fused"] = frec
     return rec

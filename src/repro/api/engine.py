@@ -50,7 +50,7 @@ from __future__ import annotations
 import math
 import time
 import warnings
-from contextlib import nullcontext
+from contextlib import ExitStack, nullcontext
 from typing import Optional, Sequence
 
 import jax
@@ -772,153 +772,170 @@ class Engine:
                 p_inj = self._inject_nan(p_inputs, start_round + i, 0)
                 ring.push(start_round + i, start_round,
                           self._extract(state, p_inj), p_inputs, p_inj)
-        for rnd in range(start_round, cfg.rounds):
-            attempts, healthy = 0, True
-            if pipelined:
-                # host-side bookkeeping only: round k's stage leaves the
-                # ring before the k+L slot is pushed, so at most L stages
-                # are ever buffered and every consumed lag is <= L
-                entry = ring.pop(rnd)
-                inputs, inj_inputs = entry.inputs, entry.inj_inputs
-                cur_lag = rnd - entry.src_round
-                max_lag = max(max_lag, cur_lag)
-                # prefetch cohort k+L's sampling while round k's compute
-                # is (or is about to be) on the devices
-                with sec("sample"):
-                    nxt_inputs = (self.sample_round(rng)
-                                  if rnd + ring_depth < cfg.rounds else None)
-                nxt_inj = (self._inject_nan(nxt_inputs, rnd + ring_depth, 0)
-                           if nxt_inputs is not None else None)
-                t_round = time.time()
-                if nxt_inputs is not None \
-                        and cfg.pipeline_staleness == "async":
-                    # overlap: extract(k+L) from the PRE-tail state — it
-                    # shares no dependency with tail(k)'s outputs, so XLA
-                    # can run it on the batch axes while the server inner
-                    # loop occupies the model axes.  Clients and the
-                    # θ_S^t snapshot are stale by exactly L rounds once
-                    # the ring is warm (less during warm-up and rewinds).
-                    ring.push(rnd + ring_depth, rnd,
-                              self._extract(state, nxt_inj),
-                              nxt_inputs, nxt_inj)
-                if self.recovery is None:
-                    with sec("dispatch"):
-                        state, metrics = self._tail(state, inj_inputs,
-                                                    entry.stage,
-                                                    self.round_key(rnd),
-                                                    lag=cur_lag)
+        # ``between_rounds``: the host path the device waits on when the
+        # host blocks on every round, from a round's sync returning to
+        # the next round's dispatch returning (tracker, callbacks, eval,
+        # the next cohort's pick, its key, the jit call).  It spans the
+        # loop boundary, so it is opened and closed by hand; a run that
+        # ends inside it (a callback raising) leaves it uncounted.
+        between_on = (prof is not None and cfg.collect_timing
+                      and sync_k == 1 and not pipelined
+                      and self.recovery is None)
+        with ExitStack() as between:
+            for rnd in range(start_round, cfg.rounds):
+                attempts, healthy = 0, True
+                if pipelined:
+                    # host-side bookkeeping only: round k's stage leaves the
+                    # ring before the k+L slot is pushed, so at most L stages
+                    # are ever buffered and every consumed lag is <= L
+                    entry = ring.pop(rnd)
+                    inputs, inj_inputs = entry.inputs, entry.inj_inputs
+                    cur_lag = rnd - entry.src_round
+                    max_lag = max(max_lag, cur_lag)
+                    # prefetch cohort k+L's sampling while round k's compute
+                    # is (or is about to be) on the devices
+                    with sec("sample"):
+                        nxt_inputs = (self.sample_round(rng)
+                                      if rnd + ring_depth < cfg.rounds
+                                      else None)
+                    nxt_inj = (self._inject_nan(nxt_inputs,
+                                                rnd + ring_depth, 0)
+                               if nxt_inputs is not None else None)
+                    t_round = time.time()
+                    if nxt_inputs is not None \
+                            and cfg.pipeline_staleness == "async":
+                        # overlap: extract(k+L) from the PRE-tail state — it
+                        # shares no dependency with tail(k)'s outputs, so XLA
+                        # can run it on the batch axes while the server inner
+                        # loop occupies the model axes.  Clients and the
+                        # θ_S^t snapshot are stale by exactly L rounds once
+                        # the ring is warm (less during warm-up and rewinds).
+                        ring.push(rnd + ring_depth, rnd,
+                                  self._extract(state, nxt_inj),
+                                  nxt_inputs, nxt_inj)
+                    if self.recovery is None:
+                        with sec("dispatch"):
+                            state, metrics = self._tail(state, inj_inputs,
+                                                        entry.stage,
+                                                        self.round_key(rnd),
+                                                        lag=cur_lag)
+                    else:
+                        (state, metrics, attempts,
+                         healthy) = self._recover_round(
+                            state, inputs, inj_inputs, rnd, stage=entry.stage,
+                            pipelined=True, lag=cur_lag)
+                        if attempts and len(ring):
+                            # every in-flight prefetch read a pre-round state
+                            # that recovery discarded — re-extract the whole
+                            # ring from the accepted state, deterministically
+                            # rewinding the schedule (the rewound stages are
+                            # fresh: their lags restart from 0)
+                            ring.rewind(lambda inj: self._extract(state, inj),
+                                        src_round=rnd + 1)
+                    if nxt_inputs is not None \
+                            and cfg.pipeline_staleness != "async":
+                        # sync barrier: extract(k+1) reads the post-Commit
+                        # state — bit-for-bit the sequential schedule
+                        ring.push(rnd + 1, rnd + 1,
+                                  self._extract(state, nxt_inj),
+                                  nxt_inputs, nxt_inj)
                 else:
-                    state, metrics, attempts, healthy = self._recover_round(
-                        state, inputs, inj_inputs, rnd, stage=entry.stage,
-                        pipelined=True, lag=cur_lag)
-                    if attempts and len(ring):
-                        # every in-flight prefetch read a pre-round state
-                        # that recovery discarded — re-extract the whole
-                        # ring from the accepted state, deterministically
-                        # rewinding the schedule (the rewound stages are
-                        # fresh: their lags restart from 0)
-                        ring.rewind(lambda inj: self._extract(state, inj),
-                                    src_round=rnd + 1)
-                if nxt_inputs is not None \
-                        and cfg.pipeline_staleness != "async":
-                    # sync barrier: extract(k+1) reads the post-Commit
-                    # state — bit-for-bit the sequential schedule
-                    ring.push(rnd + 1, rnd + 1,
-                              self._extract(state, nxt_inj),
-                              nxt_inputs, nxt_inj)
-            else:
-                with sec("sample"):
-                    # double buffer: round k-1 already sampled, padded,
-                    # and device_put this round's inputs while round
-                    # k-1's compute was in flight
-                    inputs = (nxt_inputs if nxt_inputs is not None
-                              else self.sample_round(rng))
-                    nxt_inputs = None
-                t_round = time.time()
-                if self.recovery is None:
-                    with sec("dispatch"):
-                        state, metrics = self._round_call(
-                            state, inputs, self.round_key(rnd))
-                    if rnd + 1 < cfg.rounds:
-                        # prefetch cohort k+1 behind the in-flight round
-                        # (device_put is async; nothing here blocks)
-                        with sec("sample"):
-                            nxt_inputs = self.sample_round(rng)
-                else:
-                    # recovery may re-draw quarantine weights mid-round,
-                    # so the faulted path samples strictly per round
-                    inj = self._inject_nan(inputs, rnd, 0)
-                    state, metrics, attempts, healthy = \
-                        self._recover_round(state, inputs, inj, rnd)
-            if self.recovery is not None and cfg.resilience.guard:
-                # thread the EMA carry forward and snapshot last-good
-                # states — both stay on device (no extra host sync)
-                self._ema = metrics["health"][HEALTH_EMA]
-                if healthy:
-                    self.recovery.note_accept(rnd, state, self._ema)
-            # telemetry rows are appended at sample time (for pipelined
-            # runs that's one round AHEAD of the tail); the θ staleness a
-            # round actually saw is only known here, once its tail ran
-            ti = t_tel + (rnd - start_round)
-            if ti < len(self._telemetry):
-                self._telemetry[ti]["realized_lag"] = (
-                    cur_lag if pipelined else 0)
-            if cfg.collect_timing:
-                if sync_k == 1:
-                    with sec("sync"):
-                        jax.block_until_ready(metrics["server_loss"])
-                    if rnd > start_round:         # skip the compile round
-                        round_time += time.time() - t_round
-                        timed_rounds += 1
-                elif rnd == start_round:
-                    # compile round: sync it out of the first window
-                    with sec("sync"):
-                        jax.block_until_ready(metrics["server_loss"])
-                    t_mark, r_mark = time.time(), rnd + 1
-                elif (rnd == cfg.rounds - 1
-                      or (rnd + 1 - start_round) % sync_k == 0):
-                    # window boundary: one sync covers the whole window,
-                    # timing averages over its rounds
-                    with sec("sync"):
-                        jax.block_until_ready(metrics["server_loss"])
-                    round_time += time.time() - t_mark
-                    timed_rounds += rnd + 1 - r_mark
-                    t_mark, r_mark = time.time(), rnd + 1
-            tracker.update(metrics)
-            self._emit("on_round", rnd, state, metrics)
-            if (rnd + 1) % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
-                with sec("eval"):
-                    loss, mets = evaluate(self.task, state, self.fed)
-                history.append({"round": rnd + 1, "test_loss": loss, **mets,
-                                "train_loss": float(metrics["server_loss"]),
-                                "elapsed_s": round(time.time() - t0, 1)})
-                self.log(f"[{self.algo.name}] round {rnd+1:4d} "
-                         f"test_loss={loss:.4f} "
-                         f"{self.metric_key}="
-                         f"{mets.get(self.metric_key, float('nan')):.4f}")
-                if cfg.ckpt_dir:
-                    meta = {"algo": self.algo.name}
-                    if self.recovery is not None:
-                        # persist the recovery carry a resumed run must
-                        # not forget: the quarantine ledger (+ replayable
-                        # event history) and the spike-EMA scalar
-                        # (fp32 -> python float -> fp32 is exact)
-                        meta["resilience"] = {
-                            **self.recovery.export_state(),
-                            "ema": float(jax.device_get(self._ema)),
-                        }
-                    save_checkpoint(cfg.ckpt_dir, rnd + 1, state,
-                                    metadata=meta)
-                    if self.faults is not None \
-                            and self.faults.ckpt_corrupt(rnd + 1):
-                        # tear the just-written step: restore must fall
-                        # back past it to the newest valid one
-                        self.faults.corrupt_checkpoint(cfg.ckpt_dir,
-                                                       rnd + 1)
-                        self._ckpt_corruptions += 1
-                        self.log(f"[resilience] injected torn checkpoint "
-                                 f"at step {rnd + 1}")
-                self._emit("on_eval", rnd, loss, mets)
+                    with sec("sample"):
+                        # double buffer: round k-1 already sampled, padded,
+                        # and device_put this round's inputs while round
+                        # k-1's compute was in flight
+                        inputs = (nxt_inputs if nxt_inputs is not None
+                                  else self.sample_round(rng))
+                        nxt_inputs = None
+                    t_round = time.time()
+                    if self.recovery is None:
+                        with sec("dispatch"):
+                            state, metrics = self._round_call(
+                                state, inputs, self.round_key(rnd))
+                        between.close()
+                        if rnd + 1 < cfg.rounds:
+                            # prefetch cohort k+1 behind the in-flight round
+                            # (device_put is async; nothing here blocks)
+                            with sec("sample"):
+                                nxt_inputs = self.sample_round(rng)
+                    else:
+                        # recovery may re-draw quarantine weights mid-round,
+                        # so the faulted path samples strictly per round
+                        inj = self._inject_nan(inputs, rnd, 0)
+                        state, metrics, attempts, healthy = \
+                            self._recover_round(state, inputs, inj, rnd)
+                if self.recovery is not None and cfg.resilience.guard:
+                    # thread the EMA carry forward and snapshot last-good
+                    # states — both stay on device (no extra host sync)
+                    self._ema = metrics["health"][HEALTH_EMA]
+                    if healthy:
+                        self.recovery.note_accept(rnd, state, self._ema)
+                # telemetry rows are appended at sample time (for pipelined
+                # runs that's one round AHEAD of the tail); the θ staleness a
+                # round actually saw is only known here, once its tail ran
+                ti = t_tel + (rnd - start_round)
+                if ti < len(self._telemetry):
+                    self._telemetry[ti]["realized_lag"] = (
+                        cur_lag if pipelined else 0)
+                if cfg.collect_timing:
+                    if sync_k == 1:
+                        with sec("sync"):
+                            jax.block_until_ready(metrics["server_loss"])
+                        if between_on and rnd + 1 < cfg.rounds:
+                            between.enter_context(sec("between_rounds"))
+                        if rnd > start_round:         # skip the compile round
+                            round_time += time.time() - t_round
+                            timed_rounds += 1
+                    elif rnd == start_round:
+                        # compile round: sync it out of the first window
+                        with sec("sync"):
+                            jax.block_until_ready(metrics["server_loss"])
+                        t_mark, r_mark = time.time(), rnd + 1
+                    elif (rnd == cfg.rounds - 1
+                          or (rnd + 1 - start_round) % sync_k == 0):
+                        # window boundary: one sync covers the whole window,
+                        # timing averages over its rounds
+                        with sec("sync"):
+                            jax.block_until_ready(metrics["server_loss"])
+                        round_time += time.time() - t_mark
+                        timed_rounds += rnd + 1 - r_mark
+                        t_mark, r_mark = time.time(), rnd + 1
+                tracker.update(metrics)
+                self._emit("on_round", rnd, state, metrics)
+                if (rnd + 1) % cfg.eval_every == 0 or rnd == cfg.rounds - 1:
+                    with sec("eval"):
+                        loss, mets = evaluate(self.task, state, self.fed)
+                    history.append({
+                        "round": rnd + 1, "test_loss": loss, **mets,
+                        "train_loss": float(metrics["server_loss"]),
+                        "elapsed_s": round(time.time() - t0, 1)})
+                    self.log(f"[{self.algo.name}] round {rnd+1:4d} "
+                             f"test_loss={loss:.4f} "
+                             f"{self.metric_key}="
+                             f"{mets.get(self.metric_key, float('nan')):.4f}")
+                    if cfg.ckpt_dir:
+                        meta = {"algo": self.algo.name}
+                        if self.recovery is not None:
+                            # persist the recovery carry a resumed run must
+                            # not forget: the quarantine ledger (+ replayable
+                            # event history) and the spike-EMA scalar
+                            # (fp32 -> python float -> fp32 is exact)
+                            meta["resilience"] = {
+                                **self.recovery.export_state(),
+                                "ema": float(jax.device_get(self._ema)),
+                            }
+                        save_checkpoint(cfg.ckpt_dir, rnd + 1, state,
+                                        metadata=meta)
+                        if self.faults is not None \
+                                and self.faults.ckpt_corrupt(rnd + 1):
+                            # tear the just-written step: restore must fall
+                            # back past it to the newest valid one
+                            self.faults.corrupt_checkpoint(cfg.ckpt_dir,
+                                                           rnd + 1)
+                            self._ckpt_corruptions += 1
+                            self.log(f"[resilience] injected torn checkpoint "
+                                     f"at step {rnd + 1}")
+                    self._emit("on_eval", rnd, loss, mets)
         result = {"algo": self.algo.name, "task": cfg.task,
                   "history": history, "grad_stability": tracker.summary()}
         tel = self._telemetry[t_tel:]

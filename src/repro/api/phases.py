@@ -141,6 +141,15 @@ class Phase:
         raise NotImplementedError
 
 
+def run_phase(phase: Phase, ctx: PhaseContext, v: RoundVars) -> None:
+    """Run ``phase`` under a ``jax.named_scope`` of its class name, so
+    that every op it emits carries the phase in its HLO ``op_name`` and
+    a device trace can attribute the op to it.  The scope is metadata
+    only: values and fusions are unchanged."""
+    with jax.named_scope(type(phase).__name__):
+        phase(ctx, v)
+
+
 def masked_mean(x, mask):
     """Mean over the live cohort slots (all slots when ``mask`` is None).
     With an all-ones mask this is bit-identical to ``jnp.mean``.  The
@@ -238,7 +247,8 @@ class ServerUpdate(Phase):
                 ctx.task, v.state.server, ctx.opt_server, store, v.key,
                 ctx.cycle, batch=jax.tree.leaves(v.ys)[0].shape[1],
                 mesh=ctx.mesh, grad_scale=v.stale_w)
-            v.metrics["server_loss"] = sloss
+            v.metrics["server_loss"] = sloss.mean
+            v.metrics["server_step_loss"] = sloss.per_step
         elif self.mode == "replica_avg":
             losses, gs = _pair_server_losses_and_grads(ctx, v)
             if v.stale_w is not None:
@@ -584,9 +594,9 @@ def build_algorithm(program: RoundProgram, task: SplitTask,
         v = RoundVars(state=state, cohort=cohort, xs=xs, ys=ys, key=key,
                       mask=mask, ema=ema)
         for phase in program.phases:
-            phase(ctx, v)
+            run_phase(phase, ctx, v)
         if guard is not None:
-            guard(ctx, v)
+            run_phase(guard, ctx, v)
         return v.state, v.metrics
 
     jit_kwargs = {}
@@ -732,9 +742,13 @@ def build_pipelined_algorithm(program: RoundProgram, task: SplitTask,
         traces["extract"] += 1        # executes at trace time only
         v = RoundVars(state=state, cohort=cohort, xs=xs, ys=ys, key=None,
                       mask=mask)
-        head(ctx, v)
-        store = (pool_store(v.feats, ys, mask=mask, mesh=ctx.mesh)
-                 if pools else None)
+        run_phase(head, ctx, v)
+        store = None
+        if pools:
+            # the pooling is ServerUpdate's work in the monolithic round:
+            # it keeps that phase's scope on this side of the split too
+            with jax.named_scope(ServerUpdate.__name__):
+                store = pool_store(v.feats, ys, mask=mask, mesh=ctx.mesh)
         # cycle programs: the pooled store IS the smashed data (a
         # stop_gradient + reshape of it), so handing both across the
         # dispatch boundary would materialize the cohort's features
@@ -784,9 +798,9 @@ def build_pipelined_algorithm(program: RoundProgram, task: SplitTask,
                       server_prev=stage.server_prev, feats=feats,
                       store=stage.store, stale_w=stale_w)
         for phase in tail_phases:
-            phase(ctx, v)
+            run_phase(phase, ctx, v)
         if guard is not None:
-            guard(ctx, v)
+            run_phase(guard, ctx, v)
         if stale_w is not None:
             v.metrics["stale_weight"] = stale_w
         return v.state, v.metrics

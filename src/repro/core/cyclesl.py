@@ -20,7 +20,7 @@ decoupled (both handled by the caller via ``CycleConfig``).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,10 +82,18 @@ class CycleConfig:
     # ``mesh`` argument of :func:`server_inner_loop`.
 
 
+class ServerLoss(NamedTuple):
+    """The server inner loop's loss: ``mean`` over the live steps (the
+    round's ``server_loss``) and ``per_step``, the loss of every step
+    ([E*steps]; 0 where the attendance mask skipped the step)."""
+    mean: jnp.ndarray
+    per_step: jnp.ndarray
+
+
 def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
                       store: FeatureStore, key, ccfg: CycleConfig,
                       batch: int, mesh=None,
-                      grad_scale=None) -> tuple[EntityState, jnp.ndarray]:
+                      grad_scale=None) -> tuple[EntityState, ServerLoss]:
     """E epochs of minibatch training on the resampled feature dataset.
 
     When the store carries a row-validity mask (padded cohort), the plan
@@ -112,6 +120,7 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
     GSPMD — layout only, never values.  ``grad_scale`` (a traced scalar,
     or None) multiplies every clipped gradient before the optimizer
     step — the staleness-weighting hook; 1.0 is an exact no-op.
+    Returns ``(server', ServerLoss)``.
     """
     sb = min(ccfg.server_batch or batch, store.size)
     # fused path: linear head + single integer label leaf (see below)
@@ -192,7 +201,7 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
 
     if step_ok is None:
         server, losses = jax.lax.scan(apply_step, server, plan2)
-        return server, jnp.mean(losses)
+        return server, ServerLoss(jnp.mean(losses), losses)
 
     # the loss sum rides the scan carry: sequential accumulation (with
     # exact-zero no-ops for masked steps) is invariant to how much
@@ -202,14 +211,14 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
         entity, acc = carry
         idx, ok = inp
         stepped, loss = apply_step(entity, idx)
-        return ((select_entities(ok, stepped, entity),
-                 acc + jnp.where(ok, loss, 0.0)), None)
+        live = jnp.where(ok, loss, 0.0)
+        return (select_entities(ok, stepped, entity), acc + live), live
 
     ok2 = step_ok.reshape(-1)
-    (server, loss_sum), _ = jax.lax.scan(
+    (server, loss_sum), per_step = jax.lax.scan(
         one_step, (server, jnp.zeros((), jnp.float32)), (plan2, ok2))
     denom = jnp.maximum(jnp.sum(ok2.astype(loss_sum.dtype)), 1.0)
-    return server, loss_sum / denom
+    return server, ServerLoss(loss_sum / denom, per_step)
 
 
 def feature_gradients(task: SplitTask, server_params, feats, ys,
@@ -309,7 +318,7 @@ def cyclesl_tail(task: SplitTask, server: EntityState, clients: EntityState,
     (Eq. 5), and the client VJP steps.  Returns (server', clients',
     metrics)."""
     batch = jax.tree.leaves(ys)[0].shape[1]
-    server, server_loss = server_inner_loop(
+    server, sloss = server_inner_loop(
         task, server, opt_s, store, key, ccfg, batch=batch, mesh=mesh)
 
     fgrads = feature_gradients(task, server.params, feats, ys, ccfg,
@@ -323,7 +332,7 @@ def cyclesl_tail(task: SplitTask, server: EntityState, clients: EntityState,
                                             mesh=mesh)
 
     metrics = {
-        "server_loss": server_loss,
+        "server_loss": sloss.mean,
         "feat_grad_norm_mean": jnp.mean(per_sample_norm),
         "feat_grad_norm_std": jnp.std(per_sample_norm),
         "client_grad_norm_mean": jnp.mean(client_gnorms),

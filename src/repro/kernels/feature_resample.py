@@ -108,5 +108,6 @@ def feature_resample(src, idx, *, interpret: bool = True):
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, D), src.dtype),
         interpret=interpret,
+        name="feature_resample",
     )(idx, *([src] * R))
     return unpack(out[:M])

@@ -95,6 +95,7 @@ def fused_adam(p, g, m, v, step, *, lr: float, b1: float = 0.9,
             jax.ShapeDtypeStruct((total // LANES, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_adam",
     )(bias_corrections(step, b1, b2), slab(p), slab(g), slab(m), slab(v))
     unslab = lambda x, like: x.reshape(-1)[:n].reshape(like.shape)
     return unslab(p2, p), unslab(m2, m), unslab(v2, v)

@@ -83,5 +83,6 @@ def gather_loss_microbatch(src, labels, idx, w, b: Optional[jax.Array] = None,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, LANES), jnp.float32),
         interpret=interpret,
+        name="gather_loss",
     )(idx, *([src] * R), yv, w, b)
     return out[:M, 0]

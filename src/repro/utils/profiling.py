@@ -1,23 +1,18 @@
 """Host-side round profiling: where a training round's wall time goes.
 
-Two complementary views, both built for the weak-scaling work (the
-1-device -> N-device slowdown had to be *located* before it could be
-killed):
-
 * :class:`RoundProfiler` — wall time of the HOST sections of
   ``Engine.run()`` (cohort sampling/staging, round dispatch, device
-  sync, eval).  Pass one to ``Engine(..., profiler=...)``; the run loop
-  wraps its sections and ``summary()`` reports totals, call counts, and
-  per-call means.  Zero overhead when no profiler is attached.
+  sync, eval, and the host path between two rounds).  Pass one to
+  ``Engine(..., profiler=...)``; the run loop wraps its sections and
+  ``summary()`` reports totals, call counts, and per-call means.  Each
+  section is also a ``jax.profiler.TraceAnnotation``, so a
+  ``jax.profiler`` trace holds it on the device clock.  Zero overhead
+  when no profiler is attached.
 
-* :func:`phase_costs` — wall time of each compiled RoundProgram PHASE.
-  Phases fuse into one XLA executable, so they cannot be timed from
-  inside a round; instead every program *prefix* (phases[:1],
-  phases[:2], …) is compiled and timed as its own round, and the delta
-  between consecutive prefixes attributes steady-state time to the
-  phase that was appended.  Deltas can go slightly negative when a
-  phase lets XLA dead-code-eliminate work a shorter prefix had to
-  materialize (Commit often does) — report them as-is, they are real.
+  The compiled round's PHASES are not timed here: every phase runs
+  under a ``jax.named_scope`` of its class name
+  (:func:`repro.api.phases.run_phase`), so a device trace attributes
+  each op to its phase through the op's HLO ``op_name``.
 
 * :func:`round_hlo` — the optimized HLO text of the engine's compiled
   monolithic round, for the collective census
@@ -31,6 +26,8 @@ from collections import defaultdict
 from contextlib import contextmanager
 from typing import Optional
 
+import jax
+
 
 class RoundProfiler:
     """Accumulates wall time of named host-side sections.
@@ -38,9 +35,16 @@ class RoundProfiler:
     Sections the Engine instruments: ``sample`` (cohort draw + padding +
     device placement), ``dispatch`` (the async round/extract/tail
     calls), ``sync`` (host blocks on round metrics), ``eval`` (test-set
-    evaluation).  ``dispatch`` measuring ms instead of µs is the signal
-    that rounds are NOT device-resident (the host is staging or
+    evaluation), and ``between_rounds`` (from one round's ``sync``
+    returning to the next round's ``dispatch`` returning, around the
+    ``sample`` and ``dispatch`` it contains; only where the host blocks
+    on every round).  ``dispatch`` measuring ms instead of µs is the
+    signal that rounds are NOT device-resident (the host is staging or
     blocking inside the dispatch path).
+
+    A section left by an exception is closed (its trace span ends) but
+    not counted: a run that ends inside ``between_rounds`` does not add
+    the unfinished gap.
     """
 
     def __init__(self):
@@ -49,10 +53,9 @@ class RoundProfiler:
 
     @contextmanager
     def section(self, name: str):
-        t0 = time.perf_counter()
-        try:
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
             yield
-        finally:
             self.total_s[name] += time.perf_counter() - t0
             self.calls[name] += 1
 
@@ -88,48 +91,6 @@ def _one_round_args(eng):
     key = eng.round_key(0)
     args = (state, cohort, xs, ys, key)
     return args if mask is None else args + (mask,)
-
-
-def phase_costs(eng, repeats: int = 5) -> dict:
-    """Steady-state per-phase cost of the engine's round, by prefix
-    timing.  Returns ``{phase_name: {cum_ms, delta_ms}}`` in program
-    order; ``cum_ms`` is the median round time of the prefix ending at
-    that phase, ``delta_ms`` the attribution to the phase itself."""
-    import jax
-    import numpy as np
-
-    from repro.api.phases import RoundProgram, build_algorithm
-    from repro.api.registry import get_program
-    from repro.optim import adam
-
-    cfg = eng.cfg
-    prog = get_program(cfg.algo)
-    opt_s, opt_c = adam(cfg.lr_server), adam(cfg.lr_client)
-    with _borrow_sampler(eng):
-        args = _one_round_args(eng)
-    out: dict[str, dict] = {}
-    prev = 0.0
-    for k in range(1, len(prog.phases) + 1):
-        sub = RoundProgram(prog.name, prog.phases[:k],
-                           prog.uses_global_client)
-        algo = build_algorithm(sub, eng.task, opt_s, opt_c, cfg.cycle,
-                               mesh=eng.mesh,
-                               state_shardings=eng.state_shardings,
-                               shard_data=cfg.shard_cohort)
-        jax.block_until_ready(algo.round(*args))       # compile
-        ts = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(algo.round(*args))
-            ts.append(time.perf_counter() - t0)
-        cum = float(np.median(ts)) * 1e3
-        name = type(prog.phases[k - 1]).__name__
-        while name in out:                             # repeated phase class
-            name += "'"
-        out[name] = {"cum_ms": round(cum, 3),
-                     "delta_ms": round(cum - prev, 3)}
-        prev = cum
-    return out
 
 
 def round_hlo(eng, args: Optional[tuple] = None) -> str:

@@ -105,7 +105,7 @@ class _Recorder:
         self.state = None
 
     def on_round(self, engine, rnd, state, metrics):
-        self.rows.append({k: float(v) for k, v in metrics.items()})
+        self.rows.append({k: np.asarray(v) for k, v in metrics.items()})
         self.state = state
 
 
@@ -136,7 +136,7 @@ def _legacy_loop(cfg, task, fed, with_mask=False):
                                   jnp.ones(len(cohort), jnp.float32))
         else:
             state, m = algo.round(state, jnp.asarray(cohort), xs, ys, key)
-        rows.append({k: float(v) for k, v in m.items()})
+        rows.append({k: np.asarray(v) for k, v in m.items()})
     return state, rows
 
 

@@ -87,9 +87,13 @@ def test_padded_round_matches_unpadded_bit_for_bit(name, setup):
         s_live, m_live = algo.round(s_live, cohort, xs, ys, k, mask_live)
         s_pad, m_pad = algo.round(s_pad, cohort_p, xs_p, ys_p, k, mask_p)
         for key in m_live:
+            got, want = np.asarray(m_pad[key]), np.asarray(m_live[key])
+            if key == "server_step_loss":
+                # one entry per scan step: the padded loop runs the
+                # capacity's steps, and its skipped steps read 0
+                got, want = got[got != 0], want[want != 0]
             np.testing.assert_array_equal(
-                np.asarray(m_live[key]), np.asarray(m_pad[key]),
-                err_msg=f"{name} round {r}: metric {key}")
+                want, got, err_msg=f"{name} round {r}: metric {key}")
     _assert_trees_equal(s_live.server, s_pad.server, f"{name}: server state")
     cl_live = s_live.clients if s_live.clients is not None \
         else s_live.client_global
@@ -257,3 +261,41 @@ def test_cycle_variants_share_masked_plan_semantics(setup):
                                   np.asarray(m_pad["server_loss"]))
     _assert_trees_equal(s_live.server.params, s_pad.server.params,
                         "server_steps cap under padding")
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["unpadded", "padded"])
+def test_server_step_loss_mean_is_server_loss(padded, setup):
+    """``server_step_loss`` holds every inner step's loss: its mean over
+    the live steps is the round's ``server_loss``, and the steps the
+    mask skips read 0."""
+    task, xs, ys = setup
+    cohort, cohort_p, xs_p, ys_p, mask_p = _padded(xs, ys)
+    opt = adam(5e-3)
+    epochs = 2
+    algo = build_algorithm(get_program("cyclepsl"), task, opt, opt,
+                           CycleConfig(server_epochs=epochs))
+    state = algo.init(jax.random.PRNGKey(0), n_clients=C)
+    k = jax.random.PRNGKey(0)
+    if padded:
+        _, m = algo.round(state, cohort_p, xs_p, ys_p, k, mask_p)
+    else:
+        _, m = algo.round(state, cohort, xs, ys, k)
+    steps = np.asarray(m["server_step_loss"])
+    live = steps[steps != 0]
+    assert steps.shape == ((C + PAD if padded else C) * epochs,)
+    assert live.shape == (C * epochs,)            # server batch = B
+    np.testing.assert_allclose(live.mean(), float(m["server_loss"]),
+                               rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAMS))
+def test_server_step_loss_only_in_cycle_programs(name, setup):
+    """Only a cycle-mode ServerUpdate runs an inner loop, so only it
+    reports the per-step loss."""
+    task, xs, ys = setup
+    opt = adam(5e-3)
+    algo = build_algorithm(get_program(name), task, opt, opt, CycleConfig())
+    state = algo.init(jax.random.PRNGKey(0), n_clients=C)
+    _, m = algo.round(state, jnp.arange(C), xs, ys, jax.random.PRNGKey(0),
+                      jnp.ones(C, jnp.float32))
+    assert ("server_step_loss" in m) == _is_cycle(name)

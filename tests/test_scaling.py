@@ -13,6 +13,7 @@ count binds at jax initialization.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 from dataclasses import replace
@@ -28,7 +29,7 @@ from repro.core.feature_store import FeatureStore, shard_local_fused_loss
 from repro.kernels import ops
 from repro.launch.mesh import auto_mesh
 from repro.utils.hlo_cost import assert_no_pool_allgather, collective_census
-from repro.utils.profiling import RoundProfiler, phase_costs, round_hlo
+from repro.utils.profiling import RoundProfiler, round_hlo
 
 TINY = dict(task="image", rounds=3, n_clients=8, attendance=0.5, batch=4,
             width=4, eval_every=3, seed=0)
@@ -193,22 +194,57 @@ def test_fused_shard_local_round_traces_once_and_trains():
 
 
 # -------------------------------------------------- profiler + phases
-def test_profiler_sections_and_phase_costs():
+def test_profiler_sections_and_phase_scopes():
     """The opt-in RoundProfiler shows up in the run result with the
-    host-side sections populated, and the per-phase prefix timer covers
-    every phase of the program."""
+    host-side sections populated (``between_rounds`` once per round
+    boundary when the host blocks on every round), and the compiled
+    round's ops carry the scopes of all five phases."""
     prof = RoundProfiler()
     cfg = ExperimentConfig(algo="cyclesfl", collect_timing=True,
-                           mesh_shape=(1, 1), sync_every=2, **TINY)
+                           mesh_shape=(1, 1), sync_every=1, **TINY)
     eng = Engine(cfg, donate=False, profiler=prof,
                  log=lambda *a, **k: None)
     res = eng.run()
-    assert set(res["profile"]) >= {"sample", "dispatch", "eval"}
+    assert set(res["profile"]) >= {"sample", "dispatch", "eval", "sync",
+                                   "between_rounds"}
     assert res["profile"]["dispatch"]["calls"] == cfg.rounds
-    costs = phase_costs(eng, repeats=1)
-    assert set(costs) == {"ExtractFeatures", "ServerUpdate",
-                          "FeatureGradients", "ClientUpdate", "Commit"}
-    assert "HloModule" in round_hlo(eng)
+    assert res["profile"]["between_rounds"]["calls"] == cfg.rounds - 1
+    text = round_hlo(eng)
+    assert "HloModule" in text
+    scopes = set(re.findall(r'op_name="jit\(round_impl\)/(\w+)/', text))
+    assert scopes >= {"ExtractFeatures", "ServerUpdate", "FeatureGradients",
+                      "ClientUpdate", "Commit"}
+
+
+def test_profiler_sections_are_nested_spans_in_a_trace(tmp_path):
+    """With a RoundProfiler attached, a ``jax.profiler`` trace holds the
+    Engine's sections as host spans: ``between_rounds`` encloses the
+    next round's cohort pick and ``dispatch``, and not the prefetch
+    sample that follows the dispatch."""
+    from jax.profiler import ProfileData
+    cfg = ExperimentConfig(algo="cyclepsl", collect_timing=True,
+                           sync_every=1, **{**TINY, "rounds": 2})
+    eng = Engine(cfg, donate=False, profiler=RoundProfiler(),
+                 log=lambda *a, **k: None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    pb, = tmp_path.rglob("*.xplane.pb")
+    spans = {}
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("between_rounds", "dispatch", "sample"):
+                        spans.setdefault(e.name, []).append(
+                            (e.start_ns, e.end_ns))
+    between, = spans["between_rounds"]
+    inside = lambda name: [s for s in spans[name]
+                           if between[0] <= s[0] and s[1] <= between[1]]
+    assert len(spans["dispatch"]) == 2 and len(inside("dispatch")) == 1
+    assert len(spans["sample"]) == 3 and len(inside("sample")) == 1
 
 
 # ------------------------------------------------- forced 8-device golden
