@@ -165,15 +165,20 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
         if step_ok is not None:
             step_ok = step_ok[:, : ccfg.server_steps]
     plan2 = plan.reshape(-1, sb)                     # [E*steps, sb]
+    # every gather reads the pool as 2-D [T, prod(row)] rows: flatten it
+    # once here, not per step, or the scan body relayouts the whole pool
+    # on every step (XLA does not hoist it); gathered rows are reshaped
+    # back to ``row_shape``, which is value-exact
+    row_shape = store.features.shape[1:]
+    pool = store._replace(features=store.features.reshape((store.size, -1)))
 
     def fused_step_loss(params, idx):
         w = task.server_head(params)
         if shard_local:
-            return shard_local_fused_loss(store, idx, w, mesh,
+            return shard_local_fused_loss(pool, idx, w, mesh,
                                           use_kernel=use_kernel)
         from repro.kernels import ops
-        return ops.fused_gather_loss_mean(
-            store.features.reshape((store.size, -1)), store.labels, idx, w)
+        return ops.fused_gather_loss_mean(pool.features, pool.labels, idx, w)
 
     def apply_step(entity, idx):
         if fused:
@@ -181,11 +186,12 @@ def server_inner_loop(task: SplitTask, server: EntityState, opt_s: Optimizer,
                                                               idx)
         else:
             if shard_local:
-                f, y = shard_local_gather(store, idx, mesh,
+                f, y = shard_local_gather(pool, idx, mesh,
                                           use_kernel=use_kernel,
                                           replicate_out=tp_layout)
             else:
-                f, y = gather_batch(store, idx, use_kernel=use_kernel)
+                f, y = gather_batch(pool, idx, use_kernel=use_kernel)
+            f = f.reshape((f.shape[0],) + row_shape)
             if mesh is not None:
                 from repro.sharding.specs import constrain_server_batch
                 f, y = constrain_server_batch(f, y, mesh,

@@ -160,3 +160,75 @@ def test_stage_split_e2e_equals_composition(rng):
     np.testing.assert_allclose(
         np.asarray(task.predict(cp, sp, x)),
         np.asarray(model.apply(params, x)), atol=1e-6)
+
+
+def _row_shaped_loop(task, server, opt, store, key, epochs, sb, use_kernel):
+    """Reference server inner loop that gathers every step's minibatch
+    from the row-shaped [T, ...] pool (``gather_batch`` on the store as
+    pooled): the same plan, steps, masking and loss accounting as
+    :func:`server_inner_loop`."""
+    from repro.core.feature_store import masked_resample_plan
+    from repro.core.protocol import entity_step, select_entities
+    if store.valid is None:
+        plan = resample_plan(key, store.size, epochs, sb)
+        ok = jnp.ones(plan.shape[:2], bool)
+    else:
+        plan, ok = masked_resample_plan(key, store.valid, epochs, sb)
+
+    def one_step(carry, inp):
+        entity, acc = carry
+        idx, live = inp
+        f, y = gather_batch(store, idx, use_kernel=use_kernel)
+        loss, grads = jax.value_and_grad(task.server_loss)(entity.params,
+                                                           f, y)
+        stepped = entity_step(entity, grads, opt)
+        loss = jnp.where(live, loss, 0.0)
+        return (select_entities(live, stepped, entity), acc + loss), loss
+
+    ok2 = ok.reshape(-1)
+    (server, acc), per_step = jax.lax.scan(
+        one_step, (server, jnp.zeros((), jnp.float32)),
+        (plan.reshape(-1, sb), ok2))
+    if store.valid is None:
+        return server, jnp.mean(per_step), per_step
+    return server, acc / jnp.maximum(jnp.sum(ok2.astype(acc.dtype)), 1.0), \
+        per_step
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["unpadded", "padded"])
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "kernel"])
+def test_flattened_pool_loop_is_bit_for_bit_row_shaped(rng, use_kernel,
+                                                       padded):
+    """The inner loop gathers from the pool flattened once to [T, D] and
+    reshapes each minibatch back to its row shape.  On a server half
+    whose first layer is a conv (FEMNIST CNN, cut 1: rows [14, 14, w])
+    that is bit-for-bit the loop gathering from the row-shaped pool:
+    server params and optimizer state, the mean loss and every step's
+    loss, for the jnp gather and the (interpreted) resample kernel."""
+    task = make_stage_task(femnist_cnn(n_classes=10, width=4), cut=1,
+                           kind="xent")
+    C, b = 4, 8
+    opt = adam(1e-3)
+    server = init_entity(task.init_server(jax.random.PRNGKey(0)), opt)
+    client = init_entity(task.init_client(jax.random.PRNGKey(1)), opt)
+    xs = jnp.asarray(rng.normal(size=(C, b, 28, 28, 1)), jnp.float32)
+    ys = jnp.asarray(rng.integers(0, 10, size=(C, b)))
+    feats = jax.vmap(task.client_forward, in_axes=(None, 0))(client.params,
+                                                             xs)
+    mask = jnp.asarray([1.0, 1.0, 0.0, 1.0]) if padded else None
+    store = FeatureStore.pool(feats, ys, mask=mask)
+    assert store.features.shape == (C * b, 14, 14, 4)
+    key = jax.random.PRNGKey(2)
+    ccfg = CycleConfig(server_epochs=2, resample_use_kernel=use_kernel)
+
+    got_s, got_l = jax.jit(lambda s, st: server_inner_loop(
+        task, s, opt, st, key, ccfg, batch=b))(server, store)
+    want_s, want_mean, want_steps = jax.jit(lambda s, st: _row_shaped_loop(
+        task, s, opt, st, key, 2, b, use_kernel))(server, store)
+    for g, w in zip(jax.tree.leaves(got_s), jax.tree.leaves(want_s)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got_l.mean),
+                                  np.asarray(want_mean))
+    np.testing.assert_array_equal(np.asarray(got_l.per_step),
+                                  np.asarray(want_steps))
+    assert int(got_s.step) == (2 * (3 if padded else 4))

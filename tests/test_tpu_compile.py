@@ -14,6 +14,7 @@ process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -142,3 +143,82 @@ def test_engine_round_compiles_on_4_chip_v5e_mesh(topo, monkeypatch):
     text = eng.algo.round.lower(*args).compile().as_text()
     assert C % 4 == 0 and np.prod(mesh.devices.shape) == 4
     assert "tpu_custom_call" in text
+
+
+def _hlo_computations(text: str) -> dict[str, list[str]]:
+    """The compiled module's computations: name -> instruction lines."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            lines = comps[head.group(1)] = []
+        elif line == "}":
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _reachable(comps: dict[str, list[str]], root: str) -> set[str]:
+    """``root`` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+@pytest.mark.parametrize("algo,task,width,cut,n_clients,img,row", [
+    ("cyclepsl", "image", 32, 2, 178, (28, 28, 1), 7 * 7 * 64),
+    ("cyclesfl", "cifar", 64, 4, 25, (32, 32, 3), 8 * 8 * 256),
+], ids=["femnist_cnn-cut2", "resnet9-cut4"])
+def test_inner_loop_body_copies_no_pool(one_chip, monkeypatch, algo, task,
+                                        width, cut, n_clients, img, row):
+    """The Engine's round on one described v5e: the body of the server
+    inner loop's ``while`` holds no copy of the whole pooled store.
+
+    The pool enters the loop in the layout its producer left it
+    (N-minor at these shapes), while the resample kernel reads row-major
+    [T, prod(row)] rows.  Flattened inside the scan body, that relayout
+    ran on every step (the compiler does not hoist it); the inner loop
+    now flattens the pool once, before the scan.  Without that hoist
+    this test fails: at a cohort of 178 (FEMNIST CNN, cut 2) or 25
+    (ResNet9, cut 4) and batch 32 the body copies the [T, D] pool."""
+    from repro.api import Engine, ExperimentConfig
+
+    # the kernels' backend gates on; no mesh, as in the benchmark's cells
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ExperimentConfig(algo=algo, task=task, width=width, cut=cut,
+                           n_clients=n_clients, attendance=1.0, batch=32,
+                           rounds=1, seed=0)
+    eng = Engine(cfg, log=lambda *a, **k: None)
+    C = eng.padded_capacity
+    state = jax.eval_shape(lambda: eng.algo.init(jax.random.PRNGKey(0),
+                                                 eng.fed.n_clients))
+    state = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), state)
+    args = (state, _spec(one_chip, (C,), jnp.int32),
+            _spec(one_chip, (C, 32) + img),
+            _spec(one_chip, (C, 32), jnp.int32),
+            _spec(one_chip, (2,), jnp.uint32),
+            _spec(one_chip, (C,)))
+    text = eng.algo.round.lower(*args).compile().as_text()
+
+    comps = _hlo_computations(text)
+    bodies = [m.group(1) for lines in comps.values() for line in lines
+              if " while(" in line and "ServerUpdate/while" in line
+              for m in [re.search(r"body=%([\w.\-]+)", line)]]
+    assert C == n_clients and len(bodies) == 1, bodies
+    pool = C * 32 * row
+    copies = [line.strip()[:160]
+              for name in _reachable(comps, bodies[0])
+              for line in comps[name]
+              for dims in re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", line)
+              if np.prod([int(d) for d in dims.split(",") if d]) == pool]
+    assert not copies, copies
