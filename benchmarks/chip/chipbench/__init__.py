@@ -1,1 +1,1 @@
-"""The chip benchmark's own code: data, reference, FLOP counter, trace reduction."""
+"""The chip benchmark's own code: harness, reference, FLOP counter, trace reduction."""

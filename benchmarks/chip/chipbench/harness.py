@@ -4,8 +4,11 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Its files,
 found by name under ``benchmarks/chip``:
 
-  configs/<config>.json    the model, its widths, input shape and population
+  configs/<config>.json    the model, its widths and its population
   configs/<reference>.py   the model's plain reference (named by the config)
+                           and everything else that is the model's: the
+                           weights, their split at the cut, the loss, the
+                           population and the program's task (``INTERFACE``)
   traffic/<traffic>.json   algorithm, cut, attendance, batch, mesh, and the
                            round semantics the reference follows
   limits/<cell>.json       the limit of each number that decides ``correct``
@@ -41,6 +44,9 @@ CHECKOUT = BENCH.parents[1]
 WARMUP = 3            # set-up rounds; the reference follows all three
 TRACE_ROUNDS = 20     # rounds a --trace 1 run traces, after the window
 GIB = float(1 << 30)
+# what every configuration's reference module provides (PERF.md §4)
+INTERFACE = ("init", "split", "client_forward", "server_forward", "loss",
+             "population", "sample_spec", "program_task", "tiny")
 
 
 def read_json(path: Path) -> dict:
@@ -76,14 +82,22 @@ def load_cell(name: str, checkout: Path = CHECKOUT,
     if name not in cells:
         raise KeyError(f"unknown workload {name!r}: {sorted(cells)}")
     w = cells[name]
-    config = read_json(bench / "configs" / f"{w['config']}.json")
+    configs = bench / "configs"
+    config = read_json(configs / f"{w['config']}.json")
+    # a reference module may import its siblings under configs/
+    if str(configs) not in sys.path:
+        sys.path.append(str(configs))
+    model = load_module(configs / f"{config['reference']}.py",
+                        f"chipref_{config['reference']}")
+    missing = [f for f in INTERFACE if not callable(getattr(model, f, None))]
+    if missing:
+        raise AttributeError(f"configs/{config['reference']}.py lacks "
+                             f"{missing}")
     mine = lambda m: "workloads" not in m or name in m["workloads"]
     return Cell(
         name, w["chips"], config,
         read_json(bench / "traffic" / f"{w['traffic']}.json"),
-        read_json(bench / "limits" / f"{name}.json"),
-        load_module(bench / "configs" / f"{config['reference']}.py",
-                    f"chipref_{config['reference']}"),
+        read_json(bench / "limits" / f"{name}.json"), model,
         [m for m in manifest["end_to_end"] if mine(m)],
         [m for m in manifest["per_layer"] if mine(m)])
 
@@ -150,26 +164,31 @@ class WindowClosed(Exception):
 
 
 # ------------------------------------------------------------ the cell
+def federated(x: np.ndarray, y: np.ndarray, n_test: int):
+    """The program's ``FederatedDataset`` over views of ``x``/``y``
+    (``[N, n, ...]``): each client's last ``n_test`` samples are its
+    test split."""
+    from repro.data.federated import ClientData, FederatedDataset
+    n_train = x.shape[1] - n_test
+    return FederatedDataset([
+        ClientData(x[i, :n_train], y[i, :n_train], x[i, n_train:],
+                   y[i, n_train:]) for i in range(x.shape[0])])
+
+
 def experiment(cell: Cell, seed: int):
     """The program's task, data and config for ``cell``."""
     from repro.api import ExperimentConfig
     from repro.core.cyclesl import CycleConfig
-    from repro.core.split import make_stage_task
-    from repro.models import cnn
 
-    from chipbench.data import federated, make_population
     c, t = cell.config, cell.traffic
-    pop = dict(c["population"], samples_per_client=c["samples_per_client"])
-    x, y = make_population(pop, tuple(c["input_shape"]), c["n_classes"], seed)
-    fed = federated(x, y, pop["test_per_client"])
-    pm = c["program_model"]
-    task = make_stage_task(getattr(cnn, pm["builder"])(**pm["kwargs"]),
-                           cut=t["cut"], kind="xent")
-    # ``task`` only has to name a registered task: the Engine is handed
-    # the benchmark's own task and data
+    x, y = cell.model.population(c, seed)
+    fed = federated(x, y, c["population"]["test_per_client"])
+    task = cell.model.program_task(c, t)
+    # the config's default ``task`` only has to name a registered task:
+    # the Engine is handed the benchmark's own task and data
     cfg = ExperimentConfig(
-        algo=t["algo"], task="image", rounds=10 ** 9,
-        n_clients=pop["n_clients"], attendance=t["attendance"],
+        algo=t["algo"], rounds=10 ** 9,
+        n_clients=fed.n_clients, attendance=t["attendance"],
         batch=t["batch"], lr_server=t["lr_server"], lr_client=t["lr_client"],
         seed=seed % (1 << 31), cut=t["cut"], eval_every=10 ** 9,
         collect_timing=True, sync_every=1,
@@ -187,12 +206,11 @@ def initial_state(eng, cell: Cell, seed: int):
     on the device in one jitted call, and a copy of those weights."""
     import jax
     import jax.numpy as jnp
-    cut = cell.traffic["cut"]
     shapes = jax.eval_shape(eng.init_state)
 
     def make(key):
         theta = cell.model.init(key, cell.config)
-        client, server = theta[:cut], theta[cut:]
+        client, server = cell.model.split(theta, cell.traffic["cut"])
 
         def fill(ent, params, n=None):
             if n is not None:
@@ -227,9 +245,10 @@ def _clients(state):
     return state.clients if state.clients is not None else state.client_global
 
 
-def program_norms(kind: str):
-    """jitted per-leaf norms of the program's state: ``m1`` (Adam's
-    first moment) or ``change`` (params minus the initial weights)."""
+def program_norms(cell: Cell):
+    """jitted per-leaf norms of the program's state: ``m1(state)``
+    (Adam's first moment) and ``change(state, theta0)`` (params minus
+    the initial weights)."""
     import jax
 
     from chipbench.reference import leaf_norms
@@ -238,13 +257,13 @@ def program_norms(kind: str):
         return (leaf_norms(state.server.opt_state["m"]),
                 leaf_norms(_clients(state).opt_state["m"]))
 
-    def change(state, theta0, cut):
+    def change(state, theta0):
+        client0, server0 = cell.model.split(theta0, cell.traffic["cut"])
         sub = lambda a, b: jax.tree.map(lambda x, y: x - y, a, b)
-        return (leaf_norms(sub(state.server.params, theta0[cut:])),
-                leaf_norms(sub(_clients(state).params, theta0[:cut])))
+        return (leaf_norms(sub(state.server.params, server0)),
+                leaf_norms(sub(_clients(state).params, client0)))
 
-    return jax.jit(m1) if kind == "m1" else jax.jit(change,
-                                                    static_argnums=2)
+    return jax.jit(m1), jax.jit(change)
 
 
 class Window:
@@ -264,7 +283,7 @@ class Window:
         self.gc_s, self._gc_t = [], None  # the window's collections
         self.gc_setup_s = 0.0
         self.span, self.traced = None, 0
-        self._m1, self._change = program_norms("m1"), program_norms("change")
+        self._m1, self._change = program_norms(cell)
 
     def on_compile(self, event: str, *a, **k):
         if self.t0 is not None and self.t_end is None and "compile" in event:
@@ -287,7 +306,7 @@ class Window:
                 self.norms["m1"] = jax.device_get(self._m1(state))
             if rnd == WARMUP - 1:
                 self.norms["change"] = jax.device_get(self._change(
-                    state, self.theta0, self.cell.traffic["cut"]))
+                    state, self.theta0))
                 self._open_window()
             return
         self.failed += not math.isfinite(loss)
@@ -426,23 +445,39 @@ def reference_readings(cell: Cell, theta0, rounds, **kw) -> dict:
 
 
 # ------------------------------------------------------------- metrics
+def forward_halves(cell: Cell) -> dict:
+    """From shapes alone: the client's and the server's parameters
+    (``client``, ``server``), one sample and its label (``x``, ``y``),
+    its smashed features (``f``), and the forward FLOPs of the sample
+    through each half (``client_flops``, ``server_flops``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench.flops import forward_flops
+    c, cut, m = cell.config, cell.traffic["cut"], cell.model
+    prec = jax.lax.Precision.DEFAULT
+    cp, sp = jax.eval_shape(
+        lambda: m.split(m.init(jax.random.PRNGKey(0), c), cut))
+    xs, ys = m.sample_spec(c)
+    x = jax.ShapeDtypeStruct((1, *xs.shape), xs.dtype)
+    cf = lambda cp, x: m.client_forward(cp, x, c, cut, prec, jnp.float32)
+    f = jax.eval_shape(cf, cp, x)
+    sf = lambda sp, f: m.server_forward(sp, f, c, cut, prec)
+    return {"client": cp, "server": sp, "x": x, "y": ys, "f": f,
+            "client_flops": forward_flops(cf, cp, x),
+            "server_flops": forward_flops(sf, sp, f)}
+
+
 def round_work(cell: Cell) -> dict:
     """Per-round counts from the cell's shapes: live samples, the
     model FLOPs, and the kernels' useful bytes."""
     import jax
-    import jax.numpy as jnp
 
-    from chipbench.flops import forward_flops, round_flops
+    from chipbench.flops import round_flops
     c, t = cell.config, cell.traffic
     live = round(t["attendance"] * c["population"]["n_clients"])
     rows = live * t["batch"]
-    cut, m = t["cut"], cell.model
-    p = jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0), c))
-    x = jax.ShapeDtypeStruct((1, *c["input_shape"]), jnp.float32)
-    hi, prec = m.n_stages(c), jax.lax.Precision.DEFAULT
-    cf = lambda cp, x: m.apply_range(cp, x, 0, cut, prec)
-    f = jax.eval_shape(cf, p[:cut], x)
-    sf = lambda sp, f: m.apply_range(sp, f, cut, hi, prec)
+    h = forward_halves(cell)
     mode = t["server_mode"]
     if mode == "cycle":
         steps = rows // t["server_batch"] * t["server_epochs"]
@@ -450,15 +485,15 @@ def round_work(cell: Cell) -> dict:
     else:
         steps, stepped = 1, 0
     size = lambda tree: sum(a.size for a in jax.tree.leaves(tree))
-    row_bytes = math.prod(f.shape[1:]) * 4
+    nbytes = lambda a: math.prod(a.shape) * a.dtype.itemsize
+    row_bytes = nbytes(h["f"]) + nbytes(h["y"])   # features and label
     return {
         "live": live, "rows": rows,
-        "flops": round_flops(forward_flops(cf, p[:cut], x),
-                             forward_flops(sf, p[cut:], f), mode, rows,
-                             stepped),
-        "fused_adam_bytes": 7 * 4 * (size(p[cut:]) * steps
-                                     + size(p[:cut]) * live),
-        "feature_resample_bytes": (2 * stepped * (row_bytes + 4)
+        "flops": round_flops(h["client_flops"], h["server_flops"], mode,
+                             rows, stepped),
+        "fused_adam_bytes": 7 * 4 * (size(h["server"]) * steps
+                                     + size(h["client"]) * live),
+        "feature_resample_bytes": (2 * stepped * row_bytes
                                    if mode == "cycle" else 0),
     }
 

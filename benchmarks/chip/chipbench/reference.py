@@ -17,10 +17,11 @@ One round over a cohort of C clients, each with a batch of b samples:
   5. commit: per-client states are kept (``per_client``) or the cohort's
      states are averaged into the one shared client (``average``)
 
-The loss of a batch is the mean softmax cross-entropy over its rows.
 Adam: b1 0.9, b2 0.999, eps 1e-8, bias-corrected, the learning rate of
-the traffic file.  The model's forward comes from the configuration's
-own reference module.  ``dtype``/``precision`` set the arithmetic;
+the traffic file.  The model comes from the configuration's own
+reference module: its weights' split at the cut, its two forward
+passes, and its loss, the mean softmax cross-entropy over every label
+of a batch's rows.  ``dtype``/``precision`` set the arithmetic;
 ``half=True`` takes every batch mean over the first half of the rows
 only (a planted fault).
 """
@@ -60,14 +61,6 @@ def adam(e: dict, g, lr: float) -> dict:
             "step": e["step"] + 1}
 
 
-def xent(logits, y, half: bool):
-    ll = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(ll, y[:, None], axis=-1)[:, 0]
-    if half:
-        nll = nll[: nll.shape[0] // 2]
-    return jnp.mean(nll)
-
-
 def resample_order(key, rows: int, epochs: int, batch: int) -> jax.Array:
     """[epochs * steps, batch] row indices: each epoch's rows sorted by
     their own uniform draw; the tail that fills no batch is dropped."""
@@ -85,14 +78,15 @@ def make_round(model, mcfg: dict, traffic: dict, dtype, precision,
                half: bool = False):
     """jitted ``(server, cohort, xs, ys, key) -> (server, cohort, loss)``:
     ``cohort`` is the stacked client entities of the round's C clients."""
-    cut, hi = traffic["cut"], model.n_stages(mcfg)
+    cut = traffic["cut"]
     lr_s, lr_c = traffic["lr_server"], traffic["lr_client"]
 
     def client_fwd(cp, x):
-        return model.apply_range(cp, x.astype(dtype), 0, cut, precision)
+        return model.client_forward(cp, x, mcfg, cut, precision, dtype)
 
     def server_loss(sp, f, y):
-        return xent(model.apply_range(sp, f, cut, hi, precision), y, half)
+        return model.loss(model.server_forward(sp, f, mcfg, cut, precision),
+                          y, half)
 
     def feat_grad(sp, f, y):
         return jax.grad(lambda ff: server_loss(sp, ff, y))(f)
@@ -103,9 +97,9 @@ def make_round(model, mcfg: dict, traffic: dict, dtype, precision,
         pre = server["params"]
         mode = traffic["server_mode"]
         if mode == "cycle":
-            c, b = ys.shape
+            c, b = ys.shape[:2]
             pool_f = feats.reshape((c * b,) + feats.shape[2:])
-            pool_y = ys.reshape(-1)
+            pool_y = ys.reshape((c * b,) + ys.shape[2:])
             order = resample_order(key, c * b, traffic["server_epochs"],
                                    traffic["server_batch"])
 
@@ -145,20 +139,20 @@ def leaf_norms(tree) -> dict:
         l.astype(jnp.float32)))) for p, l in flat}
 
 
-def run(model, mcfg: dict, traffic: dict, theta0: list, rounds: list, *,
+def run(model, mcfg: dict, traffic: dict, theta0, rounds: list, *,
         dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
         half: bool = False) -> dict:
     """Drive the reference through ``rounds`` (each a dict of host
     ``cohort`` ids, ``xs``, ``ys`` of the live clients and the round
-    ``key``) from the weights ``theta0`` (all stages).
+    ``key``) from the weights ``theta0`` (the whole model).
 
     Returns ``loss`` per round, the per-leaf norms of Adam's first
     moment after round 1 (``m1``: ``server/...`` and ``client/...``,
     client norms over every client) and of the parameters' change after
     the last round (``change``)."""
-    cut = traffic["cut"]
-    server = entity(theta0[cut:], dtype)
-    client0 = entity(theta0[:cut], dtype)
+    client_theta0, server_theta0 = model.split(theta0, traffic["cut"])
+    server = entity(server_theta0, dtype)
+    client0 = entity(client_theta0, dtype)
     round_fn = make_round(model, mcfg, traffic, dtype, precision, half)
     shared = traffic["commit"] == "average"
     ids = sorted({int(c) for r in rounds for c in r["cohort"]})
@@ -194,7 +188,7 @@ def run(model, mcfg: dict, traffic: dict, theta0: list, rounds: list, *,
     change = lambda new, old: jax.tree.map(
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), new, old)
     out["change"] = {
-        **norms(change(server["params"], entity(theta0[cut:], dtype)["params"]),
+        **norms(change(server["params"], entity(server_theta0, dtype)["params"]),
                 "server"),
         **norms(change(stack["params"], client0["params"]), "client")}
     return out

@@ -10,16 +10,17 @@ Stages (the cut index counts them):
 NHWC, SAME padding.  Parameters are a list of one dict per stage, in
 the layout the program's ``StageModel`` uses, so that one set of
 weights made by the benchmark feeds both.  Every contraction takes the
-``precision`` and ``dtype`` it is given.
+``precision`` and ``dtype`` it is given.  The rest of the configuration
+interface (split, loss, population, program task) is ``stage_model.py``'s.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from stage_model import (forwards, loss, population,  # noqa: F401
+                         program_task, sample_spec, split, tiny)
 
-
-def n_stages(cfg: dict) -> int:
-    return 4
+N_STAGES = 4
 
 
 def init(key, cfg: dict) -> list:
@@ -66,3 +67,6 @@ def apply_range(params: list, x, lo: int, hi: int, precision) -> jax.Array:
         else:
             x = jnp.dot(x, p["lin"]["w"].astype(x.dtype), precision=precision)
     return x
+
+
+client_forward, server_forward = forwards(apply_range, N_STAGES)

@@ -12,16 +12,18 @@ Stages (the cut index counts them), widths w, 2w, 4w, 8w:
 
 Batch-norm uses the statistics of the batch it is given (training
 mode, eps 1e-5).  NHWC, SAME padding.  Parameters are a list of one
-dict per stage, in the layout the program's ``StageModel`` uses.
+dict per stage, in the layout the program's ``StageModel`` uses.  The
+rest of the configuration interface (split, loss, population, program
+task) is ``stage_model.py``'s.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from stage_model import (forwards, loss, population,  # noqa: F401
+                         program_task, sample_spec, split, tiny)
 
-
-def n_stages(cfg: dict) -> int:
-    return 7
+N_STAGES = 7
 
 
 def init(key, cfg: dict) -> list:
@@ -84,3 +86,6 @@ def apply_range(params: list, x, lo: int, hi: int, precision) -> jax.Array:
             if i != 0:
                 x = _pool(x)
     return x
+
+
+client_forward, server_forward = forwards(apply_range, N_STAGES)

@@ -8,12 +8,12 @@ import time
 
 import pytest
 
-from chipbench_cells import CELLS, harness, tiny_cell
+from chipbench_cells import CELLS, FIXTURE_CELLS, harness, tiny_cell
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_control_fails_and_program_passes(name):
-    cell = tiny_cell(name)
+@pytest.mark.parametrize("name", CELLS + list(FIXTURE_CELLS))
+def test_control_fails_and_program_passes(name, tmp_path):
+    cell = tiny_cell(name, tmp_path)
     cell.config["matmul_precision"] = "default"
     _, prog, rounds, theta0 = harness.measure(cell, 12345, 0.0, False,
                                               time.perf_counter())

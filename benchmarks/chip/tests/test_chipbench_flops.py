@@ -1,24 +1,19 @@
 """The FLOP counter against hand counts of the configurations."""
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
+import dataclasses
+
 import pytest
 
 from chipbench_cells import harness
-from chipbench.flops import forward_flops, round_flops
+from chipbench.flops import round_flops
 
 
 def halves(name: str, cut: int):
     cell = harness.load_cell(name)
-    c, m = cell.config, cell.model
-    p = jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0), c))
-    x = jax.ShapeDtypeStruct((1, *c["input_shape"]), jnp.float32)
-    prec = jax.lax.Precision.DEFAULT
-    cf = lambda cp, x: m.apply_range(cp, x, 0, cut, prec)
-    f = jax.eval_shape(cf, p[:cut], x)
-    sf = lambda sp, f: m.apply_range(sp, f, cut, m.n_stages(c), prec)
-    return forward_flops(cf, p[:cut], x), forward_flops(sf, p[cut:], f)
+    cell = dataclasses.replace(cell, traffic=dict(cell.traffic, cut=cut))
+    h = harness.forward_halves(cell)
+    return h["client_flops"], h["server_flops"]
 
 
 def test_femnist_cnn_per_image():
